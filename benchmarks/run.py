@@ -42,6 +42,7 @@ from repro.core.wavefront import (CollisionEngine, EngineConfig,
                                   traversal_cache_info)
 from repro.data.robotics import (ENVIRONMENTS, make_mpaccel_scenario,
                                  make_scene, scene_trajectories)
+from repro.launch.compile_cache import setup_compile_cache
 
 SCALE = {"points": 65536, "trajs": 6, "wps": 30, "depth": 6,
          "mpaccel_scenarios": 4, "mpaccel_points": 16384,
@@ -888,6 +889,7 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="directory for results.csv/results.json artifacts")
     args = ap.parse_args()
+    setup_compile_cache()
     if args.smoke:
         S = SMOKE_SCALE
         names = args.only.split(",") if args.only else list(SMOKE_BENCHES)

@@ -39,9 +39,8 @@ entire life, so HBM-resident frontier traffic collapses from
   seed (query, root) pair in                                 = 12 B
   packed verdict word out                                    =  4 B
   per-query cost                                             = 16 B
-plus spill traffic only when a tile's frontier overflows VMEM and pairs
-take the HBM spill ring (out + replay back in, 12 B each way):
-  per spilled pair                                           = 24 B
+(children past a tile's VMEM frontier are dropped and counted, never
+written to HBM; the escalation replay re-reads the seeds).
 Under the RESIDENT metadata layout the node-metadata and OBB tables
 stream HBM->VMEM once per *kernel* (not per level), amortized across
 every pair of every level — the closest TPU analogue of the paper's
@@ -81,7 +80,6 @@ BYTES_UNFUSED_TEST = 424
 BYTES_FUSED_TEST = 92
 BYTES_FUSED_STEP = 40
 BYTES_PERSIST_QUERY = 16
-BYTES_PERSIST_SPILL = 24
 BYTES_META_STREAM = 16
 BYTES_META_STREAM_BF16 = 8
 BYTES_META_STREAM_U8 = 4

@@ -120,7 +120,12 @@ def _tabletop_boxes(rs, n_objects=9):
 
 
 def make_scene(name: str, seed: int = 0, num_points: int = 524288) -> Scene:
-    rs = np.random.RandomState(seed + hash(name) % 1000)
+    # The scene seed derives from the environment's position in
+    # ENVIRONMENTS, never from ``hash(name)``: Python salts string hashes
+    # per process, so a hash-derived seed gives every process its own scene.
+    if name not in ENVIRONMENTS:
+        raise ValueError(name)
+    rs = np.random.RandomState(seed + 1000 * ENVIRONMENTS.index(name))
     if name == "cubby":
         lo, hi = _cubby_boxes(rs)
     elif name == "dresser":

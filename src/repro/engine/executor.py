@@ -51,9 +51,9 @@ from repro.core import sact as sact_mod
 from repro.core.counters import (BYTES_FUSED_STEP, BYTES_FUSED_TEST,
                                  BYTES_META_STREAM, BYTES_META_STREAM_BF16,
                                  BYTES_META_STREAM_U8, BYTES_PAYLOAD_LANE,
-                                 BYTES_PERSIST_QUERY, BYTES_PERSIST_SPILL,
-                                 BYTES_SHADER_HANDOFF, BYTES_UNFUSED_TEST,
-                                 NUM_EXIT_CODES, Counters)
+                                 BYTES_PERSIST_QUERY, BYTES_SHADER_HANDOFF,
+                                 BYTES_UNFUSED_TEST, NUM_EXIT_CODES,
+                                 Counters)
 from repro.core.geometry import OBBs
 from repro.core.octree import (MAX_DEPTH, DeviceOctree, Octree,
                                concat_device_octrees, device_octree,
@@ -64,8 +64,9 @@ from repro.core.sact import (NUM_AXES, PAYLOAD_INF, SactResult,
                              payload_min_update)
 from repro.engine.plan import QueryPlan, plan_batch, plan_queries, plan_scenes
 from repro.kernels.compact.ops import compact_pairs
+from repro.kernels.persist import ops as persist_ops
 from repro.kernels.persist.ops import (DEFAULT_VMEM_BUDGET, build_tile_map,
-                                       choose_meta_layout,
+                                       choose_meta_layout, meta_table_bytes,
                                        persist_kernel_unsupported,
                                        traverse_whole)
 from repro.kernels.traverse.ops import traverse_step
@@ -110,7 +111,6 @@ class EngineConfig:
     min_bucket: int = 1024         # smallest frontier allocation
     query_block: int = 128         # naive-mode OBB block size
     frontier_capacity: Optional[int] = None  # device engine: static capacity
-    use_pallas_compact: Optional[bool] = None  # None = auto (TPU only)
     use_pallas_traverse: Optional[bool] = None  # fused step / persistent
     #                                            megakernel; None = auto
     # Persistent-megakernel metadata residency (DESIGN.md §3): budget for
@@ -176,6 +176,14 @@ class EngineConfig:
         return self.mode in DEVICE_MODES
 
 
+def _kernel_arm(cfg: EngineConfig) -> bool:
+    """Whether Pallas kernels serve this config: pinned by
+    ``use_pallas_traverse``, else exactly when the backend is a TPU."""
+    if cfg.use_pallas_traverse is not None:
+        return cfg.use_pallas_traverse
+    return jax.default_backend() == "tpu"
+
+
 def _bucket(n: int, cfg: EngineConfig) -> int:
     b = cfg.min_bucket
     while b < n:
@@ -238,6 +246,40 @@ def _escalate(run, num_queries: int, worst: int, cfg: EngineConfig,
         replays += 1
 
 
+def _tile_frontier_guard(run_arm, memo: dict, memo_key, tag: str):
+    """Escalation ``run(cap)`` for a persistent plan: the megakernel
+    arm (``run_arm(cap, True)``) while more capacity can still help it,
+    else the ref arm (``run_arm(cap, False)``).
+
+    The megakernel holds at most :data:`MAX_TILE_FRONTIER` lanes per
+    query tile, however large the global capacity.  Once a kernel run at
+    that capacity or above still overflows, a replay cannot grow the
+    tile's frontier, so the rest of the ladder runs on the ref arm, whose
+    frontier is the whole capacity.  The switch is logged as a warning,
+    counted in ``Counters.ref_arm_fallbacks`` and memoized per plan shape
+    (repeat plans start on the ref arm).  Returns ``(run, took_ref)``;
+    ``took_ref()`` says whether the ref arm served the plan.
+    """
+    ref_key = ("tile_frontier",) + memo_key
+    on_ref = [memo.get(ref_key, False)]
+
+    def run(cap):
+        if not on_ref[0]:
+            verdict, st = run_arm(cap, True)
+            if (cap < persist_ops.MAX_TILE_FRONTIER
+                    or int(jax.device_get(jnp.sum(st["overflow"]))) == 0):
+                return verdict, st
+            logger.warning(
+                "persistent plan %s overflows the megakernel's %d-lane "
+                "tile frontier at capacity %d; routed to the ref arm",
+                tag, persist_ops.MAX_TILE_FRONTIER, cap)
+            on_ref[0] = True
+            memo[ref_key] = True
+        return run_arm(cap, False)
+
+    return run, lambda: on_ref[0]
+
+
 # ---------------------------------------------------------------------------
 # Device-resident traversal (one jit-compiled while_loop, no host syncs)
 # ---------------------------------------------------------------------------
@@ -274,7 +316,7 @@ def _lane_owner(owner, q_idx):
 
 
 def _traverse(obb_c, obb_h, obb_r, dev: DeviceOctree, capacity: int,
-              use_spheres: bool, use_pallas: bool, owner=None, payload=None,
+              use_spheres: bool, owner=None, payload=None,
               num_valid=None, max_depth: Optional[int] = None):
     """Full multi-level wavefront traversal for one query set / one scene.
 
@@ -353,8 +395,7 @@ def _traverse(obb_c, obb_h, obb_r, dev: DeviceOctree, capacity: int,
         child_mask = (expand[:, None] & found).reshape(-1)          # (cap*8,)
         n_new = jnp.sum(child_mask.astype(jnp.int32))
         cnt, q_next, codes_next = compact_pairs(
-            child_mask, jnp.repeat(q_idx, 8), cand.reshape(-1), capacity,
-            use_pallas=use_pallas)
+            child_mask, jnp.repeat(q_idx, 8), cand.reshape(-1), capacity)
         st["overflow"] = st["overflow"] + jnp.maximum(n_new - capacity, 0)
         return level + 1, cnt, q_next, codes_next, verdict, st
 
@@ -372,9 +413,8 @@ def _traverse(obb_c, obb_h, obb_r, dev: DeviceOctree, capacity: int,
 
 
 def _traverse_fused(obb_c, obb_h, obb_r, dev: DeviceOctree, capacity: int,
-                    use_spheres: bool, use_pallas: bool,
-                    use_pallas_traverse: Optional[bool], owner=None,
-                    payload=None, num_valid=None,
+                    use_spheres: bool, use_pallas_traverse: Optional[bool],
+                    owner=None, payload=None, num_valid=None,
                     max_depth: Optional[int] = None):
     """Fused multi-level wavefront traversal (``mode="wavefront_fused"``).
 
@@ -405,8 +445,7 @@ def _traverse_fused(obb_c, obb_h, obb_r, dev: DeviceOctree, capacity: int,
         n_next, q_next, idx_next, verdict, info = traverse_step(
             obb_c, obb_h, obb_r, dev, level, n_live, q_idx, node_idx,
             verdict, use_spheres=use_spheres,
-            use_pallas=use_pallas_traverse, use_pallas_compact=use_pallas,
-            owner=owner, payload=payload)
+            use_pallas=use_pallas_traverse, owner=owner, payload=payload)
         res, valid, is_term = info["res"], info["valid"], info["is_term"]
         if capped:
             cap_hit = (res.collide & valid & ~is_term
@@ -452,7 +491,7 @@ _UNSET = object()
 
 @functools.lru_cache(maxsize=None)
 def _traversal_fn(mode: str, batch: str, capacity: int, use_spheres: bool,
-                  use_pallas, use_pallas_traverse, streamed: bool = False,
+                  use_pallas_traverse, streamed: bool = False,
                   meta_format: str = "fp32",
                   max_depth: Optional[int] = None):
     """One jit-compiled traversal per (mode, batch kind, capacity, statics).
@@ -471,8 +510,8 @@ def _traversal_fn(mode: str, batch: str, capacity: int, use_spheres: bool,
     keying it here keeps the cache observability honest when the same
     engine shape flips format).
     """
-    key = (mode, batch, capacity, use_spheres, use_pallas,
-           use_pallas_traverse, streamed, meta_format, max_depth)
+    key = (mode, batch, capacity, use_spheres, use_pallas_traverse,
+           streamed, meta_format, max_depth)
 
     def base(c, h, r, d, soq=None, owner=None, payload=None, tiles=None):
         _TRACE_COUNTS[key] = _TRACE_COUNTS.get(key, 0) + 1
@@ -496,11 +535,10 @@ def _traversal_fn(mode: str, batch: str, capacity: int, use_spheres: bool,
                                   tiles=tiles)
         if mode == "wavefront_fused":
             return _traverse_fused(c, h, r, d, capacity, use_spheres,
-                                   use_pallas, use_pallas_traverse,
-                                   owner=owner, payload=payload,
-                                   max_depth=max_depth)
-        return _traverse(c, h, r, d, capacity, use_spheres, use_pallas,
-                         owner=owner, payload=payload, max_depth=max_depth)
+                                   use_pallas_traverse, owner=owner,
+                                   payload=payload, max_depth=max_depth)
+        return _traverse(c, h, r, d, capacity, use_spheres, owner=owner,
+                         payload=payload, max_depth=max_depth)
 
     if batch == "single":
         fn = base
@@ -519,7 +557,7 @@ def _traversal_fn(mode: str, batch: str, capacity: int, use_spheres: bool,
 
 @functools.lru_cache(maxsize=None)
 def _sharded_traversal_fn(mode: str, capacity: int, use_spheres: bool,
-                          use_pallas, use_pallas_traverse, streamed: bool,
+                          use_pallas_traverse, streamed: bool,
                           shards: int, max_depth: Optional[int] = None):
     """Sharded sibling of :func:`_traversal_fn` (DESIGN.md §6).
 
@@ -535,8 +573,8 @@ def _sharded_traversal_fn(mode: str, capacity: int, use_spheres: bool,
     """
     from repro.parallel.sharding import (make_collision_mesh,
                                          shard_collision_traversal)
-    key = (mode, "sharded", capacity, use_spheres, use_pallas,
-           use_pallas_traverse, streamed, shards, max_depth)
+    key = (mode, "sharded", capacity, use_spheres, use_pallas_traverse,
+           streamed, shards, max_depth)
 
     def local(nv, c, h, r, d):
         _TRACE_COUNTS[key] = _TRACE_COUNTS.get(key, 0) + 1
@@ -549,10 +587,10 @@ def _sharded_traversal_fn(mode: str, capacity: int, use_spheres: bool,
                                   streamed=streamed, num_valid=nv)
         if mode == "wavefront_fused":
             return _traverse_fused(c, h, r, d, capacity, use_spheres,
-                                   use_pallas, use_pallas_traverse,
-                                   num_valid=nv, max_depth=max_depth)
-        return _traverse(c, h, r, d, capacity, use_spheres, use_pallas,
-                         num_valid=nv, max_depth=max_depth)
+                                   use_pallas_traverse, num_valid=nv,
+                                   max_depth=max_depth)
+        return _traverse(c, h, r, d, capacity, use_spheres, num_valid=nv,
+                         max_depth=max_depth)
 
     mesh = make_collision_mesh(shards)
     sm = jax.jit(shard_collision_traversal(local, mesh))
@@ -615,7 +653,6 @@ def _stats_to_counters(st, mode: str, replays: int = 0,
     if mode == "wavefront_persistent":
         seeds = int(per[0]) if per.size else 0
         c.bytes_moved = (seeds * (BYTES_PERSIST_QUERY + extra)
-                         + c.frontier_overflow * BYTES_PERSIST_SPILL
                          + c.meta_bytes_streamed)
     elif mode == "wavefront_fused":
         c.bytes_moved = c.nodes_traversed * (BYTES_FUSED_STEP + extra)
@@ -788,6 +825,19 @@ class CollisionEngine:
             self._dev[fmt] = device_octree(self.octree, meta_format=fmt)
         return self._dev[fmt]
 
+    def _replicated_tree(self, shards: int) -> DeviceOctree:
+        """The fp32 scene tables placed once on every device of the
+        ``shards``-device collision mesh, so a sharded launch does not
+        copy them out from one device each time."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.parallel.sharding import make_collision_mesh
+        key = ("replicated", shards)
+        if key not in self._dev:
+            self._dev[key] = jax.device_put(
+                self._device_tree("fp32"),
+                NamedSharding(make_collision_mesh(shards), PartitionSpec()))
+        return self._dev[key]
+
     @property
     def device_tree(self) -> DeviceOctree:
         """Packed level arrays for the device-resident engine (lazy); the
@@ -925,9 +975,17 @@ class CollisionEngine:
         upt = (self.cfg.use_pallas_traverse
                if use_pallas_traverse is _UNSET else use_pallas_traverse)
         return _traversal_fn(self.cfg.mode, batch, capacity,
-                             self.cfg.use_spheres,
-                             self.cfg.use_pallas_compact,
-                             upt, streamed, meta_format, max_depth)
+                             self.cfg.use_spheres, upt, streamed,
+                             meta_format, max_depth)
+
+    def _guarded(self, run_arm, upt, memo_key, plan: QueryPlan):
+        """``(run, took_ref)`` for one plan's escalation ladder: the
+        persistent mode's kernel arm goes through
+        :func:`_tile_frontier_guard`; every other arm runs as ``upt``."""
+        if not (self.cfg.persistent and upt):
+            return (lambda cap: run_arm(cap, upt)), (lambda: False)
+        return _tile_frontier_guard(run_arm, self._cap_memo, memo_key,
+                                    plan.shape_tag)
 
     def _exec_device(self, plan: QueryPlan,
                      max_depth: Optional[int] = None):
@@ -948,16 +1006,14 @@ class CollisionEngine:
         # left are named capability gaps — counted in
         # ``Counters.ref_arm_fallbacks`` and logged with the plan shape,
         # never silent.
-        kernel_arm = (cfg.use_pallas_traverse
-                      if cfg.use_pallas_traverse is not None
-                      else jax.default_backend() == "tpu")
+        kernel_arm = _kernel_arm(cfg)
         fallback_reason = None
         if cfg.persistent:
             fallback_reason = persist_kernel_unsupported(
                 owner, plan.scene_of_query)
             if fallback_reason is not None:
                 if kernel_arm:
-                    logger.debug(
+                    logger.warning(
                         "persistent plan %s routed to the ref arm: %s",
                         plan.shape_tag, fallback_reason)
                 kernel_arm = False
@@ -996,16 +1052,17 @@ class CollisionEngine:
                 max(cfg.max_frontier, Q))
             memo_key = ("csr_scenes", Q, plan.grouped, self._scene_sig)
             if tiled:
-                run = lambda cap: self._run(
+                run_arm = lambda cap, arm: self._run(
                     cap, streamed=streamed, meta_format=fmt,
-                    use_pallas_traverse=upt)(
+                    use_pallas_traverse=arm)(
                         *run_args, multi, None, owner_t, payload_t, tiles)
             else:
-                run = lambda cap: self._run(
+                run_arm = lambda cap, arm: self._run(
                     cap, streamed=streamed, meta_format=fmt,
-                    use_pallas_traverse=upt)(
+                    use_pallas_traverse=arm)(
                         plan.obb_c, plan.obb_h, plan.obb_r, multi,
                         plan.scene_of_query, owner, payload)
+            run, took_ref = self._guarded(run_arm, upt, memo_key, plan)
             verdict, st, cap, replays = _escalate(
                 run, Q, worst, cfg, start=self._cap_memo.get(memo_key))
         elif plan.num_scenes > 1:
@@ -1019,6 +1076,7 @@ class CollisionEngine:
                 [len(l.codes) for l in t.levels], M, cfg)
                 for t in self.octrees)
             memo_key = ("pad_scenes", S, M, self._scene_sig)
+            took_ref = lambda: False
             verdict, st, cap, replays = _escalate(
                 lambda cap: self._run(cap, "scenes")(
                     plan.obb_c.reshape(S, M, 3), plan.obb_h.reshape(S, M, 3),
@@ -1028,17 +1086,18 @@ class CollisionEngine:
             memo_key = ("single", Q, plan.grouped, max_depth,
                         self._scene_sig)
             if tiled:
-                run = lambda cap: self._run(
+                run_arm = lambda cap, arm: self._run(
                     cap, streamed=streamed, meta_format=fmt,
-                    use_pallas_traverse=upt)(
+                    use_pallas_traverse=arm)(
                         *run_args, self.device_tree, None, owner_t,
                         payload_t, tiles)
             else:
-                run = lambda cap: self._run(
+                run_arm = lambda cap, arm: self._run(
                     cap, streamed=streamed, meta_format=fmt,
-                    use_pallas_traverse=upt, max_depth=max_depth)(
+                    use_pallas_traverse=arm, max_depth=max_depth)(
                         plan.obb_c, plan.obb_h, plan.obb_r,
                         self.device_tree, None, owner, payload)
+            run, took_ref = self._guarded(run_arm, upt, memo_key, plan)
             verdict, st, cap, replays = _escalate(
                 run, Q, self._capacity(Q), cfg,
                 start=self._cap_memo.get(memo_key))
@@ -1047,7 +1106,7 @@ class CollisionEngine:
                  + (plan.payload is not None))
         counters = _stats_to_counters(st, cfg.mode, replays,
                                       extra_lanes=lanes, meta_format=fmt)
-        if cfg.persistent and fallback_reason is not None:
+        if cfg.persistent and (fallback_reason is not None or took_ref()):
             counters.ref_arm_fallbacks = 1
         verdict = np.asarray(jax.device_get(verdict))
         if plan.grouped:
@@ -1100,6 +1159,17 @@ class CollisionEngine:
             raise ValueError(
                 "sharded execution serves boolean plans; owner/payload "
                 "verdict groups span shards and stay single-device")
+        if cfg.persistent and _kernel_arm(cfg):
+            n_max = max(len(l.codes) for l in self.octree.levels)
+            need = meta_table_bytes(self.octree.depth, n_max, "fp32")
+            if need > cfg.vmem_budget:
+                raise ValueError(
+                    f"sharded wavefront_persistent pins the resident fp32 "
+                    f"metadata table, which needs {need / 2**20:.1f} MiB of "
+                    f"VMEM for this scene (budget "
+                    f"{cfg.vmem_budget / 2**20:.1f} MiB); serve it sharded "
+                    "with mode='wavefront_fused', or single-device where "
+                    "the table streams")
         shards = self.active_shards
         reshards = 0
         lost_total = 0
@@ -1118,18 +1188,20 @@ class CollisionEngine:
                     0, q_shard)
                 memo_key = ("sharded", shards, Q, max_depth,
                             self._scene_sig)
-                verdict, st, cap, replays = _escalate(
-                    lambda cap: _sharded_traversal_fn(
-                        cfg.mode, cap, cfg.use_spheres,
-                        cfg.use_pallas_compact, cfg.use_pallas_traverse,
-                        False, shards, max_depth)(
+                run, took_ref = self._guarded(
+                    lambda cap, arm: _sharded_traversal_fn(
+                        cfg.mode, cap, cfg.use_spheres, arm, False, shards,
+                        max_depth)(
                             # Sharded runs pin the resident fp32 table
                             # (see the docstring): per-device window
                             # traffic would break the partition-
                             # invariance of ``meta_rows``.
                             counts, obb_c, obb_h, obb_r,
-                            self._device_tree("fp32")),
-                    Q, self._capacity(Q), cfg,
+                            self._replicated_tree(shards)),
+                    _kernel_arm(cfg) if cfg.persistent
+                    else cfg.use_pallas_traverse, memo_key, plan)
+                verdict, st, cap, replays = _escalate(
+                    run, Q, self._capacity(Q), cfg,
                     start=self._cap_memo.get(memo_key))
                 break
             except Exception as e:
@@ -1153,6 +1225,7 @@ class CollisionEngine:
                 shards = surviving
         self._cap_memo[memo_key] = cap
         counters = _stats_to_counters(st, cfg.mode, replays)
+        counters.ref_arm_fallbacks = int(took_ref())
         counters.pad_queries = pad
         counters.reshards = reshards
         counters.shards_lost = lost_total

@@ -1,69 +1,38 @@
-"""JIT wrapper + backend dispatch for the stream-compaction kernel.
+"""Stream compaction for the per-level wavefront engines.
 
 ``stream_compact`` is the one entry point the wavefront engine calls each
-octree level.  On TPU it runs the Pallas scatter kernel (compiled); elsewhere
-it falls back to the jnp reference, because interpret-mode Pallas unrolls one
-program per grid step at trace time — untenable for million-entry frontiers.
-Both paths share the exact contract documented in ref.py, so verdicts do not
-depend on the backend.
+octree level: an XLA prefix sum plus scatter (:func:`compact_ref`), the
+same program on every backend.  A Pallas scatter kernel is not used: the
+TPU compiler has no in-kernel ``cumsum``, and a VMEM-resident output
+window does not scale to the frontier capacities of paper-size batches.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.compact.kernel import make_compact_call
 from repro.kernels.compact.ref import compact_ref
 
 
-def _use_pallas_default() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("n_out", "bn", "interpret"))
-def _compact_pallas(mask: jax.Array, vals: jax.Array, n_out: int, bn: int,
-                    interpret: bool) -> Tuple[jax.Array, jax.Array]:
-    N = mask.shape[0]
-    pad = (-N) % bn
-    m = jnp.pad(mask.astype(jnp.int32), (0, pad))
-    v = jnp.pad(vals.astype(jnp.int32), ((0, pad), (0, 0)))
-    blk_counts = m.reshape(-1, bn).sum(axis=1, dtype=jnp.int32)
-    bases = jnp.cumsum(blk_counts) - blk_counts              # exclusive scan
-    call = make_compact_call(m.shape[0], n_out, vals.shape[1], bn, interpret)
-    out = call(bases, m, v)
-    count = jnp.minimum(blk_counts.sum(), n_out)
-    return count, out[:n_out]
-
-
-def stream_compact(mask: jax.Array, vals: jax.Array, n_out: int, *,
-                   bn: int = 256, use_pallas: Optional[bool] = None,
-                   interpret: Optional[bool] = None
+def stream_compact(mask: jax.Array, vals: jax.Array, n_out: int
                    ) -> Tuple[jax.Array, jax.Array]:
     """Pack rows of ``vals`` where ``mask`` holds into an (n_out, C) buffer.
 
     Returns (count () int32, packed (n_out, C)).  Rows past ``count`` are
     unspecified; survivors that would land past ``n_out`` are dropped.
     """
-    if use_pallas is None:
-        use_pallas = _use_pallas_default()
-    if not use_pallas:
-        return compact_ref(mask, vals.astype(jnp.int32), n_out)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _compact_pallas(mask, vals, n_out, bn, interpret)
+    return compact_ref(mask, vals.astype(jnp.int32), n_out)
 
 
 def compact_pairs(mask: jax.Array, q_idx: jax.Array, codes: jax.Array,
-                  n_out: int, *, use_pallas: Optional[bool] = None
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                  n_out: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Frontier-specific wrapper: compact (query, Morton code) int32/uint32
     pairs in one pass.  Returns (count, q_idx (n_out,), codes (n_out,))."""
     vals = jnp.stack(
         [q_idx.astype(jnp.int32),
          jax.lax.bitcast_convert_type(codes, jnp.int32)], axis=-1)
-    count, packed = stream_compact(mask, vals, n_out, use_pallas=use_pallas)
+    count, packed = stream_compact(mask, vals, n_out)
     return (count, packed[:, 0],
             jax.lax.bitcast_convert_type(packed[:, 1], jnp.uint32))

@@ -1,6 +1,6 @@
-"""Pure-jnp oracle for stream compaction (prefix-sum + scatter).
+"""Stream compaction in jnp (prefix-sum + scatter), the one implementation.
 
-Contract shared with the Pallas kernel: given ``mask (N,)`` and row payloads
+Contract: given ``mask (N,)`` and row payloads
 ``vals (N, C)``, pack the rows where ``mask`` is True — in ascending input
 order — into the first ``count = min(sum(mask), n_out)`` rows of an
 ``(n_out, C)`` buffer.  Rows past ``count`` are unspecified (callers gate on

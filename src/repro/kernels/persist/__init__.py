@@ -1,6 +1,6 @@
 # Persistent whole-traversal megakernel: the ENTIRE multi-level wavefront
 # walk in one pallas_call — per-tile double-buffered VMEM frontier, in-kernel
-# level loop, in-register CSR expansion/compaction, HBM spill ring, and (for
+# level loop, in-register CSR expansion/compaction, and (for
 # scenes past the VMEM residency budget) double-buffered HBM->VMEM streaming
 # of per-level node-metadata windows.  The jnp reference arm mirrors it with
 # live-prefix width scheduling and models the same window schedule.  Backs

@@ -7,33 +7,43 @@ returns, never spilling intermediates.  The per-level fused step
 (:mod:`repro.kernels.traverse`) still launches one kernel per octree level
 and round-trips the compacted frontier through HBM between levels; this
 kernel removes that last HBM round trip.  The grid walks tiles of ``bq``
-pool slots, and each grid step owns its tile's traversal end to end:
+pool slots, and each grid step owns its tile's traversal end to end.
 
-  1. the tile's frontier lives in a **double-buffered VMEM scratch** pair
-     ``(2, fcap)`` of (query, CSR node index) lanes — level ``l`` reads
-     slot ``l % 2`` and compacts survivors' children into slot
-     ``(l + 1) % 2``; the frontier never exists in HBM;
-  2. the **level loop runs inside the kernel body** (``lax.fori_loop`` over
-     ``depth + 1`` levels; a drained frontier makes the remaining levels
-     natural no-ops — every update is masked by ``lane < n_live``);
-  3. each level gathers the lanes' query OBBs (one-hot matmul against the
-     tile's own ``bq``-row OBB block — queries never leave their tile, so
-     the full query table is never resident), reconstructs node AABBs from
-     Morton codes in-register, and runs the two-phase staged SACT via the
-     shared :func:`repro.kernels.sact.kernel.sact_tile` (tile-level
-     conditional return skips the 9 edge axes once every lane is decided);
-  4. CSR child expansion AND compaction happen **in-register**: per-parent
-     child counts (popcount of the occupancy mask) are exclusive-scanned
-     over the tile, child ``j`` of parent ``i`` lands at
-     ``base[i] + popcount(mask[i] & ((1 << j) - 1))`` — no stream-compaction
-     kernel, no candidate list in memory;
-  5. children past ``fcap`` overflow to a per-tile **HBM spill ring**
-     (``ring_cap`` most recent (query, node) pairs, wrapping) and are
-     counted — the count lands in ``Counters.frontier_overflow`` and the
-     engine's existing escalate-on-overflow policy replays the query set at
-     a larger capacity, exactly as for the per-level arms.  Spilled pairs
-     are *not* silently traversed: verdicts are exact iff the overflow
-     count is zero.
+**Layout.**  Every per-lane quantity is a ``(1, CHUNK)`` lane row, and the
+level loop runs inside the kernel body.  The tile's frontier lives in a
+VMEM scratch ``(8, fcap)`` whose rows ``3s .. 3s+2`` hold slot ``s``'s
+(query, CSR node index, parent code) lanes — level ``l`` reads slot
+``l % 2`` and writes its children to the other slot, so the frontier
+never exists in HBM.  A second ``(16, fcap)`` scratch stashes each lane's
+gathered metadata words and expansion state between the passes of a
+level.  Only the live prefix ``[0, n_live)`` is visited, ``CHUNK`` lanes
+at a time; a drained frontier makes the remaining levels no-ops.
+
+Each level makes four passes over its live chunks:
+
+  1. **gather** — node-metadata rows are gathered window by window: the
+     rows of one window are transposed into columns and each lane picks
+     its row with a one-hot reduction (a TPU core has no vector gather
+     from a large table);
+  2. **test** — decode the words in-register
+     (:func:`repro.kernels.persist.ref.decode_meta_words`, shared with the
+     ref arm), gather the lanes' query OBBs from the tile's ``bq``-row OBB
+     block with a one-hot reduction, run the two-phase staged SACT via the
+     shared :func:`repro.kernels.sact.kernel.sact_tile`, fold terminal hits
+     into the tile's per-group ``best`` column and count the work;
+  3. **scan** — a lane expands iff it overlaps a non-terminal node and its
+     payload can still beat its group's best after the level's folds; its
+     child count (popcount of the CSR occupancy mask) is prefix-summed
+     over the level;
+  4. **expand** — each next-frontier chunk finds its parents by range test
+     against the parents' [base, base + count) child ranges: child ``k`` of
+     parent ``i`` lands at ``base[i] + k`` and is node ``child_start[i] +
+     k`` — the order of the ref arm's scatter, without a scatter.
+
+Children past ``fcap`` are dropped and counted; the count lands in
+``Counters.frontier_overflow`` and the engine's escalate-on-overflow
+policy replays the query set at a larger capacity.  Verdicts are exact iff
+the overflow count is zero.
 
 **Owner-group tiling.**  The host packs the pool so every verdict group
 (all pairs sharing an ``owner_of_query`` — e.g. the segment lanes of one
@@ -41,74 +51,40 @@ swept CCD edge) lands in ONE tile (:func:`repro.kernels.persist.ops.
 build_tile_map`).  The per-tile ``owner_local`` input names each slot's
 group by the group's first slot in the tile (``-1`` = pad slot; live slots
 form each tile's prefix).  The payload min-fold and its early-exit gate
-then run on the GROUP one-hot: a terminal hit folds the lane's payload
-into ``best[owner]``, and a lane stays live only while its payload could
-still beat **its group's** best — so one segment's first hit retires its
-sibling lanes *in-kernel*, the per-edge first-hit early exit of
-swept-edge CCD.  Identity owners (``owner_local = slot``) reproduce the
+run on the group one-hot, so one segment's first hit retires its sibling
+lanes in-kernel.  Identity owners (``owner_local = slot``) reproduce the
 per-query boolean/payload kernel bit-for-bit.
 
 **Ragged multi-scene batches** run on the same flat CSR table
-(:class:`repro.core.octree.MultiSceneOctree`): tiles are scene-exclusive
-(the tile map never mixes scenes in a tile), the per-tile ``scene_of_tile``
-id picks the scene's origin/cell-size row of the flat ``scal`` table and
-its rows of the per-scene level sub-extent tables (``scene_off`` /
-``scene_counts``), and the tile's frontier seeds at the scene's root (flat
-node index ``s`` of the level-0 row).  Child pointers are pre-rebased to
-flat indices, so the walk itself is scene-blind.
+(:class:`repro.core.octree.MultiSceneOctree`): tiles are scene-exclusive,
+the per-tile ``scene_of_tile`` id picks the scene's origin/cell-size row
+of the flat ``scal`` table and its rows of the per-scene level sub-extent
+tables, and the tile's frontier seeds at the scene's root (flat node index
+``s`` of the level-0 row).
 
-Node metadata comes in one of two **layouts** (``stream`` static flag) x
-three row **formats** (``meta_fmt`` static: fp32 = 16 B, bf16 = 8 B,
-u8 = 4 B rows — :mod:`repro.core.quantize`), picked by the executor's
-layout/format chooser (DESIGN.md §3).  The compressed formats decode
-in-register via :func:`repro.kernels.persist.ref.decode_meta_rows` (shared
-with the ref arm, so geometry and topology are bitwise-identical); the u8
-format adds a third frontier lane carrying each lane's own Morton code,
-since its rows store only the node's octant:
+Node metadata reaches the kernel as ``(depth+1, words, rows/128, 128)``
+int32 — each level's words as lane-dense sheets — in one of two
+**layouts** (``stream``) x three row **formats** (``meta_fmt``: fp32 = 16
+B, bf16 = 8 B, u8 = 4 B rows — :mod:`repro.core.quantize`):
 
-* ``resident`` — the whole ``(depth+1, n_max, words)`` table is a VMEM
-  block, bounding scene size at roughly VMEM / row bytes / (depth+1)
-  nodes;
+* ``resident`` — the whole table is one single-buffered VMEM block, read in
+  aligned :data:`RESIDENT_WINDOW`-row windows;
 * ``streamed`` — the table stays in HBM (``pltpu.ANY``) and each level is
-  iterated through **fixed-size sub-level windows** of ``wsub`` rows over
-  the tile's scene sub-extent, double-buffered through a ping/pong VMEM
-  scratch pair of ``wsub + 8`` rows each: while window ``w``'s lanes run
-  their SACT out of one slot, the DMA for the tile's NEXT live window is
-  already in flight into the other (windows no lane points into are
-  skipped entirely).  The fetched span of a window is **row-exact**: the
-  occupied extent clipped to the window and rounded out to whole 8-row
-  DMA chunks (a 128-row chunk tier + an 8-row remainder tier), so a
-  shallow level costs 8 fetched rows, not a full
-  :data:`repro.core.octree.META_ROW_ALIGN` window.  VMEM scratch is
-  ``2 * (wsub + 8)`` rows — decoupled from ``n_max`` entirely, so
-  arbitrarily wide levels stream through constant VMEM.  Rows fetched are
-  counted into the ``meta_rows`` scalar, priced by the bytes model at the
-  format's row width (:data:`repro.core.counters.BYTES_META_STREAM` and
-  its ``_BF16`` / ``_U8`` siblings), with the jnp ref arm modeling the
-  identical per-(tile, window) schedule.  The row *count* per format is
-  unchanged — compression divides the streamed bytes by exactly 2x/4x.
+  iterated through fixed-size sub-level windows of ``wsub`` rows over the
+  tile's scene sub-extent, double-buffered through a ping/pong VMEM pair:
+  while window ``w`` is gathered from one slot, the DMA for the tile's
+  NEXT live window is already in flight into the other (windows no lane
+  points into are skipped).  A window's fetched span is its occupied
+  extent rounded out to whole :data:`repro.core.octree.META_ROW_ALIGN`-row
+  sheets; rows fetched are counted into the ``meta_rows`` scalar, and the
+  jnp ref arm models the identical per-(tile, window) schedule.
 
-Because pool slots are partitioned across tiles and a verdict group's
-pairs never cross tiles, the early-exit coupling (a decided group retires
-all its pairs) is tile-local, and on every clean (overflow-free) run the
-union of per-tile work is *bitwise* the work of the global-frontier ref
-arm: same pairs per level, same exit codes, same counters (summed over
-tiles and windows — the min-fold is order-free and every per-lane SACT
-result depends only on its own lane).  Overflow accounting, however, is
-per-tile: each tile owns ``fcap`` VMEM lanes, so with multiple tiles the
-aggregate frontier room is ``num_tiles * fcap`` and a frontier that
-overflows the ref's single global pool may fit here (or vice versa under
-heavy skew).  Each backend escalates against its *own* overflow count
-until clean, after which the counters agree again; only the clamped
-regime (pinned ``frontier_capacity`` / ``max_frontier``), where verdicts
-under-approximate by contract, may drop different pairs per backend.
+On clean (overflow-free) runs the union of per-tile work is bitwise the
+work of the global-frontier ref arm: same pairs per level, same exit
+codes, same counters.  Overflow accounting is per tile, so each backend
+escalates against its own overflow count until clean.
 
-Per-query HBM traffic collapses to: seed pair in, one verdict word out,
-plus spill traffic — the bytes model of
-:data:`repro.core.counters.BYTES_PERSIST_QUERY` — plus, under the
-streamed layout, the metadata window traffic above.
-
-On the CPU CI matrix the kernel (both layouts, including the DMA window
+On the CPU test matrix the kernel (both layouts, including the DMA window
 machinery) runs under ``interpret=True`` on small scenes.
 """
 from __future__ import annotations
@@ -118,409 +94,415 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.counters import NUM_EXIT_CODES
 from repro.core.octree import META_ROW_ALIGN
 from repro.core.quantize import META_FORMAT_WORDS
 from repro.core.sact import PAYLOAD_INF, axis_tests_from_exit
-from repro.kernels.persist.ref import csr_child_slots, decode_meta_rows
+from repro.kernels.persist.ref import decode_meta_words
 # _EPS shared with every SACT arm: the bitwise identity across engines
 # depends on all of them using the same epsilon and op order.
 from repro.kernels.sact.kernel import _EPS, NUM_AXES, sact_tile
 
-try:  # CPU-only containers may lack the TPU extension
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+#: Frontier lanes processed per pass.
+CHUNK = 256
+#: Rows per gather window of the resident layout (8 sheets of 128 rows).
+RESIDENT_WINDOW = 8 * META_ROW_ALIGN
+
+#: Per-tile stats column: per-level valid counts, exit histogram, scalars.
+STATS_ROWS = 48
+_HIST0, _SCAL0 = 16, 40
+_HIST_ROWS = _SCAL0 - _HIST0
+assert NUM_EXIT_CODES <= _HIST_ROWS
+#: Scalars, in order, at rows ``_SCAL0 ..`` of the stats column.
+STAT_SCALARS = ("nodes", "leaf", "axis_exec", "axis_dec", "sphere",
+                "overflow", "meta_rows")
+
+# SMEM counter slots (per-level counts occupy [0, 16)).
+_LEAF, _AXIS, _OVF, _ROWS = 16, 17, 18, 19
+# Stash scratch rows.  Rows 8..15 form one aligned group that the expand
+# pass transposes into parent columns.
+_W0, _PAY, _OWN, _MASK = 0, 4, 5, 6
+_BASE, _NCH, _START, _CODE, _Q = 8, 9, 10, 11, 12
 
 
 def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
-                   meta_ref, payload_ref, owner_ref, collide_ref,
-                   perlevel_ref, hist_ref, scalars_ref, ring_ref, *scratch,
-                   bq: int, fcap: int, depth: int, n_max: int, ring_cap: int,
-                   use_spheres: bool, stream: bool, meta_fmt: str, wsub: int):
-    # Scratch order mirrors make_persist_call's scratch_shapes: frontier
-    # query/node slot pairs always; a third frontier lane (each lane's own
-    # Morton code) under the u8 format, whose rows store only the octant;
-    # window scratch + DMA semaphores under the streamed layout.
-    fq_scr, fn_scr = scratch[0], scratch[1]
-    nscr = 2
-    fp_scr = None
-    if meta_fmt == "u8":
-        fp_scr = scratch[nscr]
-        nscr += 1
-    if stream:
-        meta_scr, dma_sem = scratch[nscr], scratch[nscr + 1]
-    t = pl.program_id(0)
+                   lane_ref, meta_ref, best_ref, stats_ref, fr_scr, st_scr,
+                   best_scr, hist_scr, cnt_smem, cb_smem, *win_scratch,
+                   bq: int, fcap: int, depth: int, n_rows: int,
+                   use_spheres: bool, stream: bool, meta_fmt: str,
+                   wsub: int):
+    C = CHUNK
     L = depth + 1
-    WS = wsub + 8                       # window scratch rows per slot
     vpf = META_FORMAT_WORDS[meta_fmt]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, fcap), 1).reshape((fcap,))
+    u8 = meta_fmt == "u8"
+    t = pl.program_id(0)
     q_base = t * bq
     s = sot_ref[t]                      # this tile's scene id
-    own_tile = owner_ref[...]           # (bq,) local owner slot, -1 = pad
+    sb = s * (3 + L)                    # this scene's row of the flat scal
+    inf = jnp.int32(PAYLOAD_INF)
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+
+    def rows_at(ref, row, c):
+        return ref[pl.ds(row, 1), pl.ds(pl.multiple_of(c * C, C), C)]
+
+    def put(ref, row, c, val):
+        ref[pl.ds(row, 1), pl.ds(pl.multiple_of(c * C, C), C)] = val
+
+    def lane_gather(onehot, col, zero):
+        """Per-lane pick of a (bq, 1) column under a (bq, C) one-hot."""
+        return jnp.sum(jnp.where(onehot, col, zero), axis=0, keepdims=True)
+
+    # ---- per-tile init + seed frontier (slot 0) ------------------------
     # Live-prefix mask: live slots form each tile's prefix (the tile map
     # pads at tile tails) AND sit before the SMEM valid count (the sharded
-    # executor's pool-tail pads) — a fully padded tile seeds an empty
-    # frontier and contributes zero work.
-    n_q = jnp.minimum(jnp.sum(jnp.where(own_tile >= 0, 1, 0)),
+    # executor's pool-tail pads) — a fully padded tile seeds nothing.
+    own_col = lane_ref[:, 1:2]
+    n_q = jnp.minimum(jnp.sum(jnp.where(own_col >= 0, 1, 0)),
                       jnp.clip(nvalid_ref[0] - q_base, 0, bq))
-
-    sb = s * (3 + L)                    # this scene's row of the flat scal
-    obb_tile = obb_ref[...]             # (bq, 15) this tile's queries
-    pay_tile = payload_ref[...]         # (bq,) payload lane per query
-    iota_q = jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1).reshape((bq,))
-    iota_hist = jax.lax.broadcasted_iota(
-        jnp.int32, (1, NUM_EXIT_CODES), 1).reshape((NUM_EXIT_CODES,))
-    inf = jnp.int32(PAYLOAD_INF)
+    best_scr[...] = jnp.full((bq, 1), inf, jnp.int32)
+    hist_scr[...] = jnp.zeros((_HIST_ROWS, 1), jnp.int32)
+    for i in range(_ROWS + 1):
+        cnt_smem[i] = jnp.int32(0)
+    for c in range(-(-bq // C)):
+        lane = c * C + iota_c
+        seed = lane < n_q
+        put(fr_scr, 0, c, jnp.where(seed, q_base + lane, 0))
+        put(fr_scr, 1, c, jnp.where(seed, s, 0))       # scene s's root
+        put(fr_scr, 2, c, jnp.zeros((1, C), jnp.int32))
 
     if stream:
-        # ---- HBM->VMEM sub-level window DMA (ping/pong scratch pair) ----
-        # Window ``w`` of this tile's scene covers flat rows
-        # [off + w*wsub, off + w*wsub + min(wsub, cnt - w*wsub)); the DMA
-        # span rounds that out to whole 8-row chunks and is issued as a
-        # 128-row chunk tier plus an 8-row remainder tier on the slot's
-        # semaphore.  The wait op re-derives the same descriptors so every
-        # started chunk is waited exactly once.
-        def _win_dma(op, level, w, slot):
-            off = off_ref[s * L + level]
-            cnt = cnt_ref[s * L + level]
-            g_lo = off + w * wsub
-            occ = jnp.clip(cnt - w * wsub, 0, wsub)
-            win_lo = (g_lo // 8) * 8
-            span = (-(-(g_lo + occ) // 8)) * 8 - win_lo
-            base = slot * WS
-            n128 = span // 128
+        meta_scr, dma_sem = win_scratch
+        lg = wsub.bit_length() - 1
+        nseg = wsub // META_ROW_ALIGN + 1     # sheets a window can span
+    else:
+        lg = RESIDENT_WINDOW.bit_length() - 1
+        nseg = RESIDENT_WINDOW // META_ROW_ALIGN
+    nw = -(-n_rows // (1 << lg))              # static window-index bound
+    nw_pad = -(-nw // 8) * 8
 
-            def chunk128(k, c):
-                dma = pltpu.make_async_copy(
-                    meta_ref.at[level, pl.ds(win_lo + k * 128, 128)],
-                    meta_scr.at[pl.ds(base + k * 128, 128)],
-                    dma_sem.at[slot])
-                (dma.start if op == "start" else dma.wait)()
-                return c
-            jax.lax.fori_loop(0, n128, chunk128, 0)
-
-            def chunk8(k, c):
-                r0 = n128 * 128 + k * 8
-                dma = pltpu.make_async_copy(
-                    meta_ref.at[level, pl.ds(win_lo + r0, 8)],
-                    meta_scr.at[pl.ds(base + r0, 8)],
-                    dma_sem.at[slot])
-                (dma.start if op == "start" else dma.wait)()
-                return c
-            jax.lax.fori_loop(0, jax.lax.rem(span, 128) // 8, chunk8, 0)
-
-    def level_body(level, carry):
-        (n_live, best_vec, per_level, hist, leaf, axis_exec, sphere,
-         overflow, spilled, cursor, ring, meta_rows) = carry
+    def level_body(level, n_live):
         slot = jax.lax.rem(level, 2)
-        q = jnp.where(slot == 0, fq_scr[0, :], fq_scr[1, :])
-        idx = jnp.where(slot == 0, fn_scr[0, :], fn_scr[1, :])
-        pcode = (jnp.where(slot == 0, fp_scr[0, :], fp_scr[1, :])
-                 if meta_fmt == "u8" else None)
-        valid = lane < n_live
-
-        # ---- per-level query-side gathers (constant across windows) ---
-        # (pool slots never cross tiles, so lane query ids are tile-local)
-        q_onehot = (q - q_base)[:, None] == iota_q[None, :]       # (fcap, bq)
-        rows = jnp.dot(q_onehot.astype(jnp.float32), obb_tile,
-                       preferred_element_type=jnp.float32)        # (fcap, 15)
-        oc = [rows[:, i] for i in range(3)]
-        oh = [rows[:, 3 + i] for i in range(3)]
-        R = [[rows[:, 6 + 3 * i + k] for k in range(3)] for i in range(3)]
-        pay_lane = jnp.sum(jnp.where(q_onehot, pay_tile[None, :], 0), axis=1)
-        # The verdict-group one-hot: folds and gates address the lane's
-        # OWNER slot, so sibling lanes of one group share one best cell.
-        # Identity owners make this the per-query one-hot of old.
-        own_lane = jnp.sum(jnp.where(q_onehot, own_tile[None, :], 0), axis=1)
-        o_onehot = own_lane[:, None] == iota_q[None, :]           # (fcap, bq)
-
+        nxt = 1 - slot
+        n_chunks = (n_live + C - 1) // C
         cell = scal_ref[sb + 3 + level]
-        node_h = cell * 0.5
-
-        def sact_window(meta, in_w, best_cur):
-            """One SACT + fold + stash pass over the lanes of one gather.
-
-            Per-lane results depend only on the lane's own inputs (the
-            edge-stage skip in :func:`sact_tile` can only *run more* work
-            when extra undecided lanes share the call, never change a
-            decided lane), so partitioning a level's lanes across windows
-            leaves every per-lane quantity — and therefore every summed
-            counter and the order-free min-fold — bitwise-identical to one
-            whole-level pass.
-            """
-            xyz_i, full_l, child_start, child_mask, code_own = \
-                decode_meta_rows(meta, meta_fmt, level, pcode)
-            xyz = xyz_i.astype(jnp.float32)
-            node_c = [scal_ref[sb + i] + (xyz[:, i] + 0.5) * cell
-                      for i in range(3)]
-            tt = [oc[i] - node_c[i] for i in range(3)]
-            A = [[jnp.abs(R[i][k]) + _EPS for k in range(3)]
-                 for i in range(3)]
-            collide_l, exit_code = sact_tile(tt, R, A, [node_h] * 3, oh,
-                                             use_spheres=use_spheres)
-            is_term = full_l | (level == depth)
-            overlap = collide_l & in_w
-            term_hit = overlap & is_term
-            # Terminal hits fold the lane's payload into its GROUP's best.
-            fold = jnp.minimum(best_cur, jnp.min(
-                jnp.where(term_hit[:, None] & o_onehot, pay_lane[:, None],
-                          inf), axis=0))
-            term_valid = jnp.where(in_w & is_term, 1, 0)
-            d_leaf = jnp.sum(term_valid)
-            d_axis = jnp.sum(
-                jnp.where(in_w, axis_tests_from_exit(exit_code), 0))
-            d_hist = jnp.sum(
-                jnp.where((exit_code[:, None] == iota_hist[None, :])
-                          & (term_valid[:, None] != 0), 1, 0), axis=0)
-            # Expansion candidates stash: a zero mask == not a candidate.
-            cand_mask = jnp.where(overlap & ~is_term, child_mask, 0)
-            return fold, d_leaf, d_axis, d_hist, cand_mask, child_start, \
-                code_own
-
+        lo = [scal_ref[sb + i] for i in range(3)]
         if stream:
             off_l = off_ref[s * L + level]
             cnt_l = cnt_ref[s * L + level]
-            nwin = -(-n_max // wsub)            # static window-index bound
-            big = jnp.int32(nwin)
-            win_lane = jnp.where(valid, (idx - off_l) // wsub, big)
-            w0 = jnp.min(win_lane)
-
-            @pl.when(w0 < big)
-            def _():
-                _win_dma("start", level, w0, 0)
-
-            def wbody(w, wc):
-                (k, fold, leaf_a, axis_a, hist_a, st_mask, st_start,
-                 st_code, rows_a) = wc
-                in_w = valid & (win_lane == w)
-                has_w = jnp.sum(jnp.where(in_w, 1, 0)) > 0
-                ks = jax.lax.rem(k, 2)
-
-                @pl.when(has_w)
-                def _():
-                    _win_dma("wait", level, w, ks)
-
-                # Put the tile's NEXT live window in flight into the other
-                # slot before any SACT work — the copy overlaps the pass.
-                nxt = jnp.min(jnp.where(valid & (win_lane > w), win_lane,
-                                        big))
-
-                @pl.when(has_w & (nxt < big))
-                def _():
-                    _win_dma("start", level, nxt, 1 - ks)
-
-                g_lo = off_l + w * wsub
-                win_lo = (g_lo // 8) * 8
-                local = jnp.clip(idx - win_lo, 0, WS - 1)
-                meta = jnp.take(meta_scr[...], ks * WS + local, axis=0)
-                f, d_leaf, d_axis, d_hist, cm, cs, co = sact_window(
-                    meta, in_w, fold)
-                occ = jnp.clip(cnt_l - w * wsub, 0, wsub)
-                span = (-(-(g_lo + occ) // 8)) * 8 - win_lo
-                return (k + jnp.where(has_w, 1, 0), f,
-                        leaf_a + d_leaf, axis_a + d_axis, hist_a + d_hist,
-                        jnp.where(in_w, cm, st_mask),
-                        jnp.where(in_w, cs, st_start),
-                        jnp.where(in_w, co, st_code),
-                        rows_a + jnp.where(has_w, span, 0))
-
-            wmax = jnp.max(jnp.where(valid, win_lane + 1, 0))
-            z = jnp.zeros((fcap,), jnp.int32)
-            (_, best_vec, d_leaf, d_axis, d_hist, st_mask, st_start,
-             st_code, d_rows) = jax.lax.fori_loop(
-                0, wmax, wbody,
-                (jnp.int32(0), best_vec, jnp.int32(0), jnp.int32(0),
-                 jnp.zeros((NUM_EXIT_CODES,), jnp.int32), z, z, z,
-                 jnp.int32(0)))
-            meta_rows = meta_rows + d_rows
         else:
-            meta = jnp.take(meta_flat,
-                            level * n_max + jnp.clip(idx, 0, n_max - 1),
-                            axis=0)
-            (best_vec, d_leaf, d_axis, d_hist, st_mask, st_start,
-             st_code) = sact_window(meta, valid, best_vec)
+            off_l = jnp.int32(0)
 
-        # ---- group-best gate + work accounting (fused-arm formulas) ---
-        best_lane = jnp.min(jnp.where(o_onehot, best_vec[None, :], inf),
-                            axis=1)
-        n_valid = jnp.sum(valid.astype(jnp.int32))
-        leaf = leaf + d_leaf
-        axis_exec = axis_exec + d_axis
-        sphere = sphere + (2 * n_valid if use_spheres else 0)
-        per_level = per_level + jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (1, L), 1).reshape((L,))
-            == level, n_valid, 0)
-        hist = hist + d_hist
+        def win_of(idx):
+            return (idx - off_l) >> lg
 
-        # ---- in-register CSR expansion + compaction -------------------
-        # A lane expands iff it stashed a candidate mask (overlap & ~term;
-        # a real candidate's mask is never 0 — a non-full internal node
-        # has at least one occupied child) and its payload could still
-        # beat its group's best AFTER this level's folds.
-        expand = (st_mask != 0) & (pay_lane < best_lane)
-        occupied, offs = csr_child_slots(st_mask)
-        n_child = jnp.where(expand,
-                            jax.lax.population_count(st_mask), 0)
-        base = jnp.cumsum(n_child) - n_child
-        n_new = jnp.sum(n_child)
-        live = expand[:, None] & occupied                          # (fcap, 8)
-        pos = base[:, None] + offs
-        q_rep = jnp.repeat(q, 8)
-        cand = (st_start[:, None] + offs).reshape(-1)
-        tgt = jnp.where(live, pos, fcap).reshape(-1)
-        q_next = jnp.zeros((fcap,), jnp.int32).at[tgt].set(q_rep,
-                                                           mode="drop")
-        i_next = jnp.zeros((fcap,), jnp.int32).at[tgt].set(cand,
-                                                           mode="drop")
+        # ---- pass 1: gather metadata words, window-major -------------
+        iota_w = jax.lax.broadcasted_iota(jnp.int32, (nw_pad, 1), 0)
 
-        # ---- HBM spill ring: children past fcap, newest-wrapping ------
-        in_ring = live & (pos >= fcap)
-        ring_tgt = jnp.where(
-            in_ring, jax.lax.rem(cursor + (pos - fcap), ring_cap),
-            ring_cap).reshape(-1)
-        ring = ring.at[ring_tgt, 0].set(q_rep, mode="drop")
-        ring = ring.at[ring_tgt, 1].set(cand, mode="drop")
-        spill_now = jnp.maximum(n_new - fcap, 0)
-        overflow = overflow + spill_now
-        spilled = spilled + spill_now
-        cursor = jax.lax.rem(cursor + spill_now, ring_cap)
+        def occ_body(c, occ):
+            valid = c * C + iota_c < n_live
+            w_row = jnp.where(valid, win_of(rows_at(fr_scr, 3 * slot + 1, c)),
+                              -1)
+            hit = jnp.where(w_row == jax.lax.broadcasted_iota(
+                jnp.int32, (nw_pad, C), 0), 1, 0)
+            return jnp.maximum(occ, jnp.max(hit, axis=1, keepdims=True))
+        occ = jax.lax.fori_loop(0, n_chunks, occ_body,
+                                jnp.zeros((nw_pad, 1), jnp.int32))
 
-        # ---- double-buffer write: next level reads the other slot -----
-        nxt = 1 - slot
-        fq_scr[0, :] = jnp.where(nxt == 0, q_next, fq_scr[0, :])
-        fq_scr[1, :] = jnp.where(nxt == 1, q_next, fq_scr[1, :])
-        fn_scr[0, :] = jnp.where(nxt == 0, i_next, fn_scr[0, :])
-        fn_scr[1, :] = jnp.where(nxt == 1, i_next, fn_scr[1, :])
-        if meta_fmt == "u8":
-            # Children inherit this lane's own code as their pcode.
-            p_next = jnp.zeros((fcap,), jnp.int32).at[tgt].set(
-                jnp.repeat(st_code, 8), mode="drop")
-            fp_scr[0, :] = jnp.where(nxt == 0, p_next, fp_scr[0, :])
-            fp_scr[1, :] = jnp.where(nxt == 1, p_next, fp_scr[1, :])
-        return (jnp.minimum(n_new, fcap), best_vec, per_level, hist,
-                leaf, axis_exec, sphere, overflow, spilled, cursor, ring,
-                meta_rows)
+        def next_window(w):
+            return jnp.min(jnp.where((iota_w > w) & (occ > 0), iota_w, nw))
 
-    if not stream:
-        meta_flat = meta_ref[...].reshape(L * n_max, vpf)
+        if stream:
+            def window_dma(op, w, ws):
+                """Start or wait the row-sheet DMAs of window ``w`` into
+                ping/pong slot ``ws``; returns the rows fetched."""
+                g_lo = off_l + w * wsub
+                occ_rows = jnp.clip(cnt_l - w * wsub, 0, wsub)
+                r0 = g_lo // META_ROW_ALIGN
+                n_sheets = ((g_lo + occ_rows + META_ROW_ALIGN - 1)
+                            // META_ROW_ALIGN - r0)
 
-    # Seed frontier (slot 0): one (query, scene root) pair per live slot of
-    # the tile.  Scene s's root sits at flat index s of the level-0 row
-    # (0 for a single scene).
-    fq_scr[0, :] = jnp.where(lane < n_q, q_base + lane, 0)
-    fn_scr[0, :] = jnp.where(lane < n_q, s, 0)
-    if meta_fmt == "u8":
-        # Scene-local codes: every scene's root code is 0.
-        fp_scr[0, :] = jnp.zeros((fcap,), jnp.int32)
+                def sheet(k, carry):
+                    dma = pltpu.make_async_copy(
+                        meta_ref.at[level, :, pl.ds(r0 + k, 1), :],
+                        meta_scr.at[ws, :, pl.ds(k, 1), :],
+                        dma_sem.at[ws])
+                    (dma.start if op == "start" else dma.wait)()
+                    return carry
+                jax.lax.fori_loop(0, n_sheets, sheet, 0)
+                return n_sheets * META_ROW_ALIGN
 
-    carry0 = (jnp.minimum(n_q, fcap),
-              jnp.full((bq,), PAYLOAD_INF, jnp.int32),
-              jnp.zeros((L,), jnp.int32),
-              jnp.zeros((NUM_EXIT_CODES,), jnp.int32),
-              jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-              jnp.int32(0), jnp.int32(0),
-              jnp.zeros((ring_cap, 2), jnp.int32),
-              jnp.int32(0))
-    (_, best_vec, per_level, hist, leaf, axis_exec, sphere, overflow,
-     spilled, _, ring, meta_rows) = jax.lax.fori_loop(0, L, level_body,
-                                                      carry0)
+            w_first = next_window(-1)
 
-    collide_ref[...] = best_vec.reshape(1, bq)
-    perlevel_ref[...] = per_level.reshape(1, L)
-    hist_ref[...] = hist.reshape(1, NUM_EXIT_CODES)
-    nodes = jnp.sum(per_level)
-    scalars_ref[...] = jnp.stack(
-        [nodes, leaf, axis_exec, nodes * NUM_AXES, sphere, overflow,
-         spilled, meta_rows]).reshape(1, 8)
-    ring_ref[...] = ring.reshape(1, ring_cap, 2)
+            @pl.when(w_first < nw)
+            def _():
+                window_dma("start", w_first, 0)
+
+        iota_seg = jax.lax.broadcasted_iota(jnp.int32, (META_ROW_ALIGN, C), 0)
+
+        def win_body(w, k):
+            has_w = jnp.sum(jnp.where(iota_w == w, occ, 0)) > 0
+
+            @pl.when(has_w)
+            def _():
+                if stream:
+                    ks = jax.lax.rem(k, 2)
+                    rows = window_dma("wait", w, ks)
+                    nx = next_window(w)
+
+                    @pl.when(nx < nw)
+                    def _():
+                        window_dma("start", nx, 1 - ks)
+
+                    cnt_smem[_ROWS] = cnt_smem[_ROWS] + rows
+                    sheet_lo = ((off_l + w * wsub) // META_ROW_ALIGN
+                                * META_ROW_ALIGN)
+                    cols = [meta_scr[ks, kk].T for kk in range(vpf)]
+                else:
+                    sheet_lo = w * RESIDENT_WINDOW
+                    cols = [meta_ref[level, kk,
+                                     pl.ds(pl.multiple_of(w * nseg, 8), nseg),
+                                     :].T
+                            for kk in range(vpf)]
+
+                def chunk_body(c, carry):
+                    valid = c * C + iota_c < n_live
+                    idx = rows_at(fr_scr, 3 * slot + 1, c)
+                    in_w = valid & (win_of(idx) == w)
+
+                    @pl.when(jnp.sum(jnp.where(in_w, 1, 0)) > 0)
+                    def _():
+                        local = idx - sheet_lo
+                        acc = [jnp.zeros((1, C), jnp.int32)] * vpf
+                        for g in range(nseg):
+                            onehot = (local - g * META_ROW_ALIGN) == iota_seg
+                            for kk in range(vpf):
+                                acc[kk] = acc[kk] + lane_gather(
+                                    onehot, cols[kk][:, g:g + 1], 0)
+                        for kk in range(vpf):
+                            put(st_scr, _W0 + kk, c,
+                                jnp.where(in_w, acc[kk],
+                                          rows_at(st_scr, _W0 + kk, c)))
+                    return carry
+                jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+            return k + jnp.where(has_w, 1, 0)
+        jax.lax.fori_loop(0, nw, win_body, jnp.int32(0))
+
+        # ---- pass 2: decode + OBB gather + staged SACT + fold ----------
+        iota_q = jax.lax.broadcasted_iota(jnp.int32, (bq, C), 0)
+        iota_e = jax.lax.broadcasted_iota(jnp.int32, (_HIST_ROWS, C), 0)
+
+        def test_body(c, n_valid):
+            valid = c * C + iota_c < n_live
+            q = rows_at(fr_scr, 3 * slot, c)
+            pcode = rows_at(fr_scr, 3 * slot + 2, c) if u8 else None
+            words = [rows_at(st_scr, _W0 + kk, c) for kk in range(vpf)]
+            xyz, full_l, child_start, child_mask, code_own = \
+                decode_meta_words(words, meta_fmt, level, pcode)
+            q_hot = (q - q_base) == iota_q                       # (bq, C)
+            f = [lane_gather(q_hot, obb_ref[:, i:i + 1], 0.0)
+                 for i in range(15)]
+            pay = lane_gather(q_hot, lane_ref[:, 0:1], 0)
+            own = lane_gather(q_hot, lane_ref[:, 1:2], 0)
+            node_c = [lo[i] + (xyz[i].astype(jnp.float32) + 0.5) * cell
+                      for i in range(3)]
+            tt = [f[i] - node_c[i] for i in range(3)]
+            R = [[f[6 + 3 * i + k] for k in range(3)] for i in range(3)]
+            A = [[jnp.abs(R[i][k]) + _EPS for k in range(3)]
+                 for i in range(3)]
+            collide, exit_code = sact_tile(tt, R, A, [cell * 0.5] * 3,
+                                           f[3:6], use_spheres=use_spheres)
+            is_term = full_l | (level == depth)
+            overlap = collide & valid
+            term_hit = overlap & is_term
+            # Terminal hits fold the lane's payload into its GROUP's best.
+            o_hot = own == iota_q
+            best_scr[...] = jnp.minimum(best_scr[...], jnp.min(
+                jnp.where(o_hot & term_hit, pay, inf), axis=1,
+                keepdims=True))
+            term_valid = valid & is_term
+            cnt_smem[_LEAF] = cnt_smem[_LEAF] + jnp.sum(
+                jnp.where(term_valid, 1, 0))
+            cnt_smem[_AXIS] = cnt_smem[_AXIS] + jnp.sum(
+                jnp.where(valid, axis_tests_from_exit(exit_code), 0))
+            hist_scr[...] = hist_scr[...] + jnp.sum(
+                jnp.where((exit_code == iota_e) & term_valid, 1, 0), axis=1,
+                keepdims=True)
+            put(st_scr, _PAY, c, pay)
+            put(st_scr, _OWN, c, own)
+            # Expansion candidates: a zero mask == not a candidate (a
+            # non-full internal node has at least one occupied child).
+            put(st_scr, _MASK, c, jnp.where(overlap & ~is_term, child_mask, 0))
+            put(st_scr, _START, c, child_start)
+            put(st_scr, _CODE, c, code_own)
+            put(st_scr, _Q, c, q)
+            return n_valid + jnp.sum(jnp.where(valid, 1, 0))
+        n_valid = jax.lax.fori_loop(0, n_chunks, test_body, jnp.int32(0))
+        cnt_smem[level] = n_valid
+
+        # ---- pass 3: group-best gate + child-count prefix sum ---------
+        tri = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+               <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+        def scan_body(c, base):
+            own = rows_at(st_scr, _OWN, c)
+            mask = rows_at(st_scr, _MASK, c)
+            best_lane = jnp.min(jnp.where(own == iota_q, best_scr[...], inf),
+                                axis=0, keepdims=True)
+            expand = (mask != 0) & (rows_at(st_scr, _PAY, c) < best_lane)
+            n_child = jnp.where(expand, jax.lax.population_count(mask), 0)
+            n_col = jnp.broadcast_to(n_child, (8, C)).T[:, 0:1]     # (C, 1)
+            incl = jnp.sum(jnp.where(tri, n_col, 0), axis=0, keepdims=True)
+            put(st_scr, _BASE, c, base + incl - n_child)
+            put(st_scr, _NCH, c, n_child)
+            cb_smem[c] = base
+            return base + jnp.sum(n_child)
+        n_new = jax.lax.fori_loop(0, n_chunks, scan_body, jnp.int32(0))
+        cb_smem[n_chunks] = n_new
+        n_next = jnp.minimum(n_new, fcap)
+        cnt_smem[_OVF] = cnt_smem[_OVF] + jnp.maximum(n_new - fcap, 0)
+
+        # ---- pass 4: place children into the other frontier slot ------
+        def out_body(o, carry):
+            p = o * C + iota_c
+
+            def parent_body(c, acc):
+                hit = (cb_smem[c] < (o + 1) * C) & (cb_smem[c + 1] > o * C)
+
+                def add(acc):
+                    par = st_scr[pl.ds(_BASE, 8),
+                                 pl.ds(pl.multiple_of(c * C, C), C)].T
+                    b = par[:, 0:1]
+                    owns = (b <= p) & (p < b + par[:, 1:2])          # (C, C)
+                    return (acc[0] + lane_gather(owns, par[:, 4:5], 0),
+                            acc[1] + lane_gather(owns, par[:, 2:3] - b, 0),
+                            acc[2] + lane_gather(owns, par[:, 3:4], 0))
+                return jax.lax.cond(hit, add, lambda a: a, acc)
+            z = jnp.zeros((1, C), jnp.int32)
+            q_n, rel_n, code_n = jax.lax.fori_loop(0, n_chunks, parent_body,
+                                                   (z, z, z))
+            live = p < n_next
+            put(fr_scr, 3 * nxt, o, jnp.where(live, q_n, 0))
+            put(fr_scr, 3 * nxt + 1, o, jnp.where(live, rel_n + p, 0))
+            # Children inherit this lane's own code as their pcode (u8).
+            put(fr_scr, 3 * nxt + 2, o, jnp.where(live, code_n, 0))
+            return carry
+        jax.lax.fori_loop(0, (n_next + C - 1) // C, out_body, 0)
+        return n_next
+
+    jax.lax.fori_loop(0, L, level_body, jnp.minimum(n_q, fcap))
+
+    # ---- outputs ---------------------------------------------------------
+    best_ref[...] = best_scr[...]
+    iota16 = jax.lax.broadcasted_iota(jnp.int32, (16, 1), 0)
+    per_level = jnp.zeros((16, 1), jnp.int32)
+    nodes = jnp.int32(0)
+    for lv in range(L):
+        per_level = jnp.where(iota16 == lv, cnt_smem[lv], per_level)
+        nodes = nodes + cnt_smem[lv]
+    stats_ref[0:_HIST0, :] = per_level
+    stats_ref[_HIST0:_SCAL0, :] = hist_scr[...]
+    vals = (nodes, cnt_smem[_LEAF], cnt_smem[_AXIS], nodes * NUM_AXES,
+            2 * nodes if use_spheres else jnp.int32(0), cnt_smem[_OVF],
+            cnt_smem[_ROWS])
+    iota8 = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    scal = jnp.zeros((8, 1), jnp.int32)
+    for i, v in enumerate(vals):
+        scal = jnp.where(iota8 == i, v, scal)
+    stats_ref[_SCAL0:STATS_ROWS, :] = scal
+
+
+def vmem_scratch_bytes(bq: int, fcap: int, vpf: int, stream: bool,
+                       wsub: int) -> int:
+    """VMEM bytes of the kernel's scratch (frontier, stash, accumulators,
+    streamed window pair), excluding the resident table and temporaries."""
+    fpad = _frontier_lanes(bq, fcap)
+    total = (8 + 16) * fpad * 4 + bq * 128 * 4 + _HIST_ROWS * 128 * 4
+    if stream:
+        total += 2 * vpf * _window_sheets(wsub) * 128 * 4
+    return total
+
+
+def _frontier_lanes(bq: int, fcap: int) -> int:
+    return -(-max(fcap, bq) // CHUNK) * CHUNK
+
+
+def _window_sheets(wsub: int) -> int:
+    return -(-(wsub // META_ROW_ALIGN + 1) // 8) * 8
 
 
 def make_persist_call(num_tiles: int, bq: int, fcap: int, depth: int,
-                      n_max: int, ring_cap: int, use_spheres: bool,
-                      interpret: bool, stream: bool, meta_fmt: str = "fp32",
-                      num_scenes: int = 1, wsub: int = 1024):
+                      n_rows: int, use_spheres: bool, interpret: bool,
+                      stream: bool, meta_fmt: str = "fp32",
+                      wsub: int = 1024, vmem_limit_bytes: int | None = None):
     """Build the whole-traversal pallas_call.
 
     Inputs: scal (S * (3 + depth+1),) f32 SMEM — per scene [scene_lo xyz,
     per-level cells], flat scene-major; scene_off / scene_counts
     (S * (depth+1),) int32 SMEM — per-scene flat sub-extents of the level
-    rows (offset 0 / total counts for a single scene); scene_of_tile
-    (num_tiles,) int32 SMEM; live query count (1,) int32 SMEM (the pool's
-    live prefix — pad slots past it never seed, see the sharded executor);
-    OBB table (num_tiles * bq, 15) f32, blocked per tile; node_meta
-    (depth+1, n_max, words) int32 packed per ``meta_fmt`` (fp32: 4 words,
-    bf16: 2, u8: 1 — :mod:`repro.core.quantize`) — a resident VMEM block,
-    or an HBM-space (``pltpu.ANY``) table streamed through the ping/pong
-    sub-level window scratch of ``wsub + 8`` rows per slot when ``stream``
-    (the DMA machinery is format-agnostic: only the row width changes);
-    payload (num_tiles * bq,) int32 per-query payload lane (all zeros for
-    boolean plans); owner_local (num_tiles * bq,) int32 per-slot verdict
-    group as the group's first tile-local slot, ``-1`` = pad (tile-local
-    identity for per-query plans).  Outputs per tile: ``best`` payload
-    words (bq,) int32 per owner slot (``PAYLOAD_INF`` = that group never
-    hit; 0 = a boolean hit), valid counts per level, exit histogram,
-    packed work scalars [nodes, leaf, axis_exec, axis_dec, sphere,
-    overflow, spilled, meta_rows], and the spill ring's (query, node)
-    pairs.
+    rows; scene_of_tile (num_tiles,) int32 SMEM; live query count (1,)
+    int32 SMEM; OBB table (num_tiles * bq, 15) f32, blocked per tile;
+    lanes (num_tiles * bq, 2) int32 [payload, owner_local] per slot
+    (owner_local = the slot's verdict group as the group's first
+    tile-local slot, ``-1`` = pad); node_meta (depth+1, words, n_rows/128,
+    128) int32 packed per ``meta_fmt`` — a single-buffered VMEM block, or
+    an HBM-space (``pltpu.ANY``) table streamed through the ping/pong
+    window scratch when ``stream``.  Outputs: ``best`` (num_tiles * bq, 1)
+    int32 per owner slot (``PAYLOAD_INF`` = that group never hit; 0 = a
+    boolean hit) and the (num_tiles * STATS_ROWS, 1) int32 stats column
+    per tile (see :data:`STATS_ROWS`).
     """
-    if pltpu is None:  # pragma: no cover - exercised only sans TPU extra
-        raise RuntimeError("pallas TPU extension unavailable")
+    assert bq % 8 == 0, "query tiles are whole 8-row OBB blocks"
+    assert n_rows % RESIDENT_WINDOW == 0, "tables are whole 1024-row windows"
     if stream:
-        assert n_max % META_ROW_ALIGN == 0, \
-            "streamed node_meta needs META_ROW_ALIGN-aligned rows"
-        assert wsub % 8 == 0 and wsub > 0, \
-            "sub-level windows are whole 8-row DMA chunks"
+        assert wsub & (wsub - 1) == 0 and wsub >= META_ROW_ALIGN, wsub
     L = depth + 1
     vpf = META_FORMAT_WORDS[meta_fmt]
+    fpad = _frontier_lanes(bq, fcap)
     kernel = functools.partial(
-        persist_kernel, bq=bq, fcap=fcap, depth=depth, n_max=n_max,
-        ring_cap=ring_cap, use_spheres=use_spheres, stream=stream,
-        meta_fmt=meta_fmt, wsub=wsub)
-    meta_spec = (pl.BlockSpec(memory_space=pltpu.ANY) if stream
-                 else pl.BlockSpec((L, n_max, vpf), lambda t: (0, 0, 0)))
+        persist_kernel, bq=bq, fcap=fcap, depth=depth, n_rows=n_rows,
+        use_spheres=use_spheres, stream=stream, meta_fmt=meta_fmt,
+        wsub=wsub)
+    sheets = n_rows // META_ROW_ALIGN
+    meta_spec = (pl.BlockSpec(memory_space=pl.ANY) if stream
+                 else pl.BlockSpec((L, vpf, sheets, META_ROW_ALIGN),
+                                   lambda t: (0, 0, 0, 0),
+                                   pipeline_mode=pl.Buffered(1)))
     scratch = [
-        pltpu.VMEM((2, fcap), jnp.int32),    # frontier queries (2 slots)
-        pltpu.VMEM((2, fcap), jnp.int32),    # frontier node indices
+        pltpu.VMEM((8, fpad), jnp.int32),          # frontier, 2 slots x 3
+        pltpu.VMEM((16, fpad), jnp.int32),         # per-lane stash
+        pltpu.VMEM((bq, 1), jnp.int32),            # per-group best
+        pltpu.VMEM((_HIST_ROWS, 1), jnp.int32),    # exit histogram
+        pltpu.SMEM((32,), jnp.int32),              # scalar counters
+        pltpu.SMEM((fpad // CHUNK + 1,), jnp.int32),   # chunk child bases
     ]
-    if meta_fmt == "u8":
-        scratch.append(pltpu.VMEM((2, fcap), jnp.int32))  # own-code lane
     if stream:
         scratch += [
-            # sub-level window ping/pong pair, flat: slot s = rows
-            # [s * (wsub + 8), (s + 1) * (wsub + 8)) — constant in n_max.
-            pltpu.VMEM((2 * (wsub + 8), vpf), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),      # per-slot window DMAs
+            pltpu.VMEM((2, vpf, _window_sheets(wsub), META_ROW_ALIGN),
+                       jnp.int32),                 # window ping/pong pair
+            pltpu.SemaphoreType.DMA((2,)),
         ]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=(num_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # scal
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # scene_off
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # scene_counts
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # scene_of_tile
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # live count
-            pl.BlockSpec((bq, 15), lambda t: (t, 0)),         # OBB tile
-            meta_spec,                                        # node meta
-            pl.BlockSpec((bq,), lambda t: (t,)),              # payload lane
-            pl.BlockSpec((bq,), lambda t: (t,)),              # owner_local
+            smem, smem, smem, smem, smem,      # scal, off, counts, sot, nv
+            pl.BlockSpec((bq, 15), lambda t: (t, 0)),
+            pl.BlockSpec((bq, 2), lambda t: (t, 0)),
+            meta_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, bq), lambda t: (t, 0)),
-            pl.BlockSpec((1, L), lambda t: (t, 0)),
-            pl.BlockSpec((1, NUM_EXIT_CODES), lambda t: (t, 0)),
-            pl.BlockSpec((1, 8), lambda t: (t, 0)),
-            pl.BlockSpec((1, ring_cap, 2), lambda t: (t, 0, 0)),
+            pl.BlockSpec((bq, 1), lambda t: (t, 0)),
+            pl.BlockSpec((STATS_ROWS, 1), lambda t: (t, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((num_tiles, bq), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, L), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, NUM_EXIT_CODES), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, 8), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, ring_cap, 2), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles * bq, 1), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles * STATS_ROWS, 1), jnp.int32),
         ],
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )

@@ -59,11 +59,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.counters import (BYTES_META_STREAM, BYTES_META_STREAM_BF16,
-                                 BYTES_META_STREAM_U8)
+                                 BYTES_META_STREAM_U8, NUM_EXIT_CODES)
 from repro.core.octree import (MAX_DEPTH, META_ROW_ALIGN, DeviceOctree,
                                MultiSceneOctree, align_rows)
-from repro.core.quantize import META_FORMATS, format_eligible
+from repro.core.quantize import META_FORMAT_WORDS, META_FORMATS, format_eligible
 from repro.core.sact import PAYLOAD_INF
+from repro.kernels.persist.kernel import (_HIST0, _SCAL0, RESIDENT_WINDOW,
+                                          STAT_SCALARS, STATS_ROWS,
+                                          _window_sheets, make_persist_call,
+                                          vmem_scratch_bytes)
 from repro.kernels.persist.ref import traverse_whole_ref
 from repro.kernels.sact.ops import pack_obbs
 
@@ -83,18 +87,10 @@ META_FORMAT_BYTES = {"fp32": BYTES_META_STREAM,
                      "bf16": BYTES_META_STREAM_BF16,
                      "u8": BYTES_META_STREAM_U8}
 
-#: Default VMEM budget for the resident node-metadata table.  Real TPU
-#: cores have ~16 MiB of VMEM; the megakernel also needs its frontier
-#: scratch, the per-tile OBB block, and (streamed) the window pair, so
-#: the table gets half.  ``EngineConfig.vmem_budget`` overrides per
-#: engine; CPU/interpret runs have no hard limit but honor the same
-#: estimate so layout choice is backend-independent.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
-
 #: Fixed sub-level window size in rows for the streamed layout: each
 #: level is iterated ``wsub`` rows at a time, so the VMEM window scratch
 #: is constant in ``n_max`` (a level narrower than this streams in one
-#: window, as the PR 5 whole-level windows did).
+#: window).
 SUB_WINDOW_ROWS = 1024
 
 #: Largest owner-group tile the megakernel will build.  A verdict group
@@ -103,31 +99,63 @@ SUB_WINDOW_ROWS = 1024
 #: fallback to the ref arm (:func:`persist_kernel_unsupported`).
 MAX_TILE_BQ = 1024
 
+#: Largest per-tile frontier (lanes) the megakernel holds in VMEM.  The
+#: engine's frontier capacity is a global bound; a tile never needs more
+#: than its own share, and past this cap a tile's children overflow (and
+#: are counted) instead of growing the scratch.
+MAX_TILE_FRONTIER = 65536
+
+#: Highest scoped-VMEM limit a megakernel call asks for: a TPU v5e
+#: TensorCore's 128 MiB of VMEM less headroom for the compiler's own
+#: internal scratch.
+KERNEL_VMEM_CAP = 100 * 1024 * 1024
+
+#: Compiler temporaries of the megakernel body (one-hot planes of the
+#: gathers and the (CHUNK, CHUNK) scan/placement planes) allowed on top of
+#: its declared scratch.  The v5e compile of the largest tile
+#: (MAX_TILE_BQ queries, MAX_TILE_FRONTIER lanes) over a depth-7 resident
+#: fp32 table of the whole DEFAULT_VMEM_BUDGET needs 6.5-6.75 MiB on top
+#: of table and scratch (it fails at a 6.5 MiB allowance, passes at
+#: 6.75 MiB; ``tests/test_tpu_compile.py`` compiles that corner); this
+#: allows more than twice that.
+KERNEL_TEMP_BYTES = 16 * 1024 * 1024
+
+#: Default VMEM budget for the resident node-metadata table: what the
+#: capped scoped limit leaves after the largest tile's scratch and the
+#: compiler's temporaries.  ``EngineConfig.vmem_budget`` overrides per
+#: engine; CPU/interpret runs have no hard limit but honor the same
+#: estimate so layout choice is backend-independent.
+DEFAULT_VMEM_BUDGET = (KERNEL_VMEM_CAP - KERNEL_TEMP_BYTES
+                       - vmem_scratch_bytes(MAX_TILE_BQ, MAX_TILE_FRONTIER, 4,
+                                            True, SUB_WINDOW_ROWS))
+
 
 def meta_table_bytes(depth: int, n_max: int, fmt: str = "fp32") -> int:
-    """VMEM bytes of the RESIDENT node-metadata table (aligned rows)."""
-    return (depth + 1) * align_rows(n_max) * META_FORMAT_BYTES[fmt]
+    """VMEM bytes of the RESIDENT node-metadata table: the kernel's
+    single-buffered block, rows padded to whole 1024-row windows."""
+    rows = -(-max(int(n_max), 1) // RESIDENT_WINDOW) * RESIDENT_WINDOW
+    return (depth + 1) * rows * META_FORMAT_BYTES[fmt]
 
 
 def sub_window_rows(n_max: int) -> int:
     """Streamed sub-level window size in rows for an ``n_max``-wide table
-    (the fixed :data:`SUB_WINDOW_ROWS`, shrunk to the aligned table width
-    when the whole table is narrower)."""
-    return min(SUB_WINDOW_ROWS, align_rows(n_max))
+    (the fixed :data:`SUB_WINDOW_ROWS`, shrunk to the power of two that
+    covers the aligned table when the whole table is narrower)."""
+    return min(SUB_WINDOW_ROWS, _next_pow2(align_rows(n_max)))
 
 
 def meta_stream_bytes(n_max: int, fmt: str = "fp32") -> int:
     """VMEM bytes of the STREAMED layout's ping/pong window pair.
 
-    Each slot holds one fixed-size sub-level window plus one 8-row DMA
-    chunk of slack (row-exact spans round the occupied extent OUT to
-    whole 8-row chunks, so a window's span can start up to 7 rows before
-    its first occupied row).  Constant in ``n_max`` once the table is
-    wider than :data:`SUB_WINDOW_ROWS`: VMEM scratch is fully decoupled
-    from the widest level, so arbitrarily large scenes stream through
-    the same budget.
+    Each slot holds the 128-row sheets one fixed-size sub-level window
+    can span (its occupied extent rounds OUT to whole sheets, so an
+    unaligned window touches one sheet more).  Constant in ``n_max`` once
+    the table is wider than :data:`SUB_WINDOW_ROWS`: VMEM scratch is fully
+    decoupled from the widest level, so arbitrarily large scenes stream
+    through the same budget.
     """
-    return 2 * (sub_window_rows(n_max) + 8) * META_FORMAT_BYTES[fmt]
+    return (2 * META_FORMAT_WORDS[fmt] * _window_sheets(sub_window_rows(n_max))
+            * META_ROW_ALIGN * 4)
 
 
 class MetaChoice(NamedTuple):
@@ -347,18 +375,40 @@ def _scene_extents(dev) -> Tuple[jax.Array, jax.Array]:
             jnp.reshape(dev.counts.astype(jnp.int32), (1, L)))
 
 
+def _kernel_meta_table(node_meta: jax.Array) -> jax.Array:
+    """(depth+1, n_max, words) packed rows -> the megakernel's
+    (depth+1, words, n_rows/128, 128) lane-dense sheets, rows padded to
+    whole :data:`repro.kernels.persist.kernel.RESIDENT_WINDOW` windows."""
+    L, n_max, vpf = node_meta.shape
+    n_rows = -(-n_max // RESIDENT_WINDOW) * RESIDENT_WINDOW
+    meta = jnp.pad(node_meta, ((0, 0), (0, n_rows - n_max), (0, 0)))
+    return jnp.transpose(meta, (0, 2, 1)).reshape(
+        L, vpf, n_rows // META_ROW_ALIGN, META_ROW_ALIGN)
+
+
+def kernel_vmem_limit(depth: int, n_max: int, fmt: str, stream: bool,
+                      bq: int, fcap: int) -> int:
+    """Scoped-VMEM limit for one megakernel call: its scratch, the
+    resident table (single-buffered) and :data:`KERNEL_TEMP_BYTES` of
+    compiler temporaries, capped at :data:`KERNEL_VMEM_CAP`."""
+    need = (vmem_scratch_bytes(bq, fcap, META_FORMAT_WORDS[fmt], stream,
+                               sub_window_rows(n_max))
+            + KERNEL_TEMP_BYTES)
+    if not stream:
+        need += meta_table_bytes(depth, n_max, fmt)
+    return min(need, KERNEL_VMEM_CAP)
+
+
 def _kernel_whole(obb_c, obb_h, obb_r, dev, capacity: int,
-                  use_spheres: bool, bq: int, ring_cap: int,
-                  interpret: bool, stream: bool, payload=None,
-                  num_valid=None, owner_local=None,
+                  use_spheres: bool, bq: int, interpret: bool, stream: bool,
+                  payload=None, num_valid=None, owner_local=None,
                   scene_of_tile=None) -> Tuple[jax.Array, dict]:
     """Run the megakernel; returns the RAW (num_tiles * bq,) per-slot
     ``best`` words (PAYLOAD_INF = that owner slot never hit) + stats."""
-    from repro.kernels.persist.kernel import make_persist_call
-
     M = obb_c.shape[0]
     L = dev.depth + 1
     n_max = dev.node_meta.shape[-2]
+    fmt = getattr(dev, "meta_format", "fp32")
     obb = pack_obbs(obb_c, obb_h, obb_r)
     pay = (jnp.zeros((M,), jnp.int32) if payload is None
            else payload.astype(jnp.int32))
@@ -381,35 +431,31 @@ def _kernel_whole(obb_c, obb_h, obb_r, dev, capacity: int,
         scal = jnp.concatenate(
             [dev.scene_lo, dev.cell_sizes], axis=1
         ).astype(jnp.float32).reshape(-1)
-        num_scenes = dev.num_scenes
     else:
         scal = jnp.concatenate([jnp.asarray(dev.scene_lo, jnp.float32),
                                 jnp.asarray(dev.cell_sizes, jnp.float32)])
-        num_scenes = 1
     off, cnt = _scene_extents(dev)
-    meta = dev.node_meta
-    if stream and n_max % META_ROW_ALIGN:   # hand-built unaligned tables
-        padr = align_rows(n_max) - n_max
-        meta = jnp.pad(meta, ((0, 0), (0, padr), (0, 0)))
-        n_max = n_max + padr
+    meta = _kernel_meta_table(dev.node_meta)
     nvalid = jnp.reshape(jnp.asarray(M if num_valid is None else num_valid,
                                      jnp.int32), (1,))
-    call = make_persist_call(num_tiles, bq, capacity, dev.depth, n_max,
-                             ring_cap, use_spheres, interpret, stream,
-                             meta_fmt=getattr(dev, "meta_format", "fp32"),
-                             num_scenes=num_scenes,
-                             wsub=sub_window_rows(n_max))
-    words, per_level, hist, scalars, _ring = call(
-        scal, off.reshape(-1), cnt.reshape(-1), sot, nvalid, obb, meta,
-        pay, own)
-    best = words.reshape(-1)
-    tot = jnp.sum(scalars, axis=0)
-    per = jnp.zeros((MAX_DEPTH + 1,), jnp.int32).at[:L].set(
-        jnp.sum(per_level, axis=0))
-    st = dict(nodes=tot[0], leaf=tot[1], axis_exec=tot[2], axis_dec=tot[3],
-              sphere=tot[4], overflow=tot[5], per_level=per,
-              exit_hist=jnp.sum(hist, axis=0), meta_rows=tot[7])
-    return best, st
+    fcap = min(capacity, MAX_TILE_FRONTIER)
+    call = make_persist_call(
+        num_tiles, bq, fcap, dev.depth, meta.shape[2] * META_ROW_ALIGN,
+        use_spheres, interpret, stream, meta_fmt=fmt,
+        wsub=sub_window_rows(n_max),
+        vmem_limit_bytes=kernel_vmem_limit(dev.depth, n_max, fmt, stream,
+                                           bq, fcap))
+    best, stats = call(scal, off.reshape(-1), cnt.reshape(-1), sot, nvalid,
+                       obb, jnp.stack([pay, own], axis=1), meta)
+    tot = jnp.sum(stats.reshape(num_tiles, STATS_ROWS), axis=0)
+    sc = dict(zip(STAT_SCALARS, tot[_SCAL0:_SCAL0 + len(STAT_SCALARS)]))
+    per = jnp.zeros((MAX_DEPTH + 1,), jnp.int32).at[:L].set(tot[:L])
+    st = dict(nodes=sc["nodes"], leaf=sc["leaf"], axis_exec=sc["axis_exec"],
+              axis_dec=sc["axis_dec"], sphere=sc["sphere"],
+              overflow=sc["overflow"], per_level=per,
+              exit_hist=tot[_HIST0:_HIST0 + NUM_EXIT_CODES],
+              meta_rows=sc["meta_rows"])
+    return best.reshape(-1), st
 
 
 def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
@@ -419,7 +465,7 @@ def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
                    owner_of_query: Optional[jax.Array] = None,
                    payload: Optional[jax.Array] = None,
                    streamed: Optional[bool] = None,
-                   bq: int = 128, ring_cap: int = 256, w_min: int = 128,
+                   bq: int = 128, w_min: int = 128,
                    num_valid=None,
                    tiles: Optional[Tiling] = None) -> Tuple[jax.Array, dict]:
     """Whole multi-level traversal for one flat query set.
@@ -522,7 +568,7 @@ def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
                             else jnp.asarray(owner_of_query)[perm]),
             payload=(None if payload is None
                      else jnp.asarray(payload)[perm]),
-            streamed=streamed, bq=tm.bq, ring_cap=ring_cap, w_min=w_min,
+            streamed=streamed, bq=tm.bq, w_min=w_min,
             tiles=jax.tree.map(jnp.asarray, tm.tiles))
 
     if tiles is not None:
@@ -535,7 +581,7 @@ def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
         if use_pallas:
             best, st = _kernel_whole(
                 obb_c, obb_h, obb_r, dev, capacity, use_spheres, bq_t,
-                ring_cap, interpret, stream=streamed, payload=payload,
+                interpret, stream=streamed, payload=payload,
                 owner_local=tiles.owner_local,
                 scene_of_tile=tiles.scene_of_tile)
         else:
@@ -574,7 +620,7 @@ def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
     M = obb_c.shape[0]
     if use_pallas:
         best, st = _kernel_whole(obb_c, obb_h, obb_r, dev, capacity,
-                                 use_spheres, bq, ring_cap, interpret,
+                                 use_spheres, bq, interpret,
                                  stream=streamed, payload=payload,
                                  num_valid=num_valid)
         best = best[:M]

@@ -50,8 +50,9 @@ parent-major), so a kernel tile touches window ``w`` at level ``l``
 exactly when some valid lane has ``q // bq == t`` and ``(node - off) //
 wsub == w`` on the global pool — bitwise on every clean run, like the
 other counters.  The fetched span of a touched window is its occupied
-extent clipped to the window and rounded OUT to whole 8-row DMA chunks
-(``floor8(lo) .. ceil8(hi)``), the kernel's exact descriptor arithmetic.
+extent clipped to the window and rounded OUT to whole
+:data:`repro.core.octree.META_ROW_ALIGN`-row DMA chunks (``floor128(lo) ..
+ceil128(hi)``), the kernel's exact descriptor arithmetic.
 """
 from __future__ import annotations
 
@@ -62,7 +63,7 @@ import jax.numpy as jnp
 
 from repro.core import sact as sact_mod
 from repro.core.counters import NUM_EXIT_CODES
-from repro.core.octree import (MAX_DEPTH, jnp_morton_decode,
+from repro.core.octree import (MAX_DEPTH, META_ROW_ALIGN, _jnp_compact1by2,
                                node_centers_from_xyz)
 from repro.core.quantize import (BF16_START_BITS, GRID_BITS, META_FORMATS,
                                  U8_START_BITS)
@@ -95,29 +96,30 @@ def csr_child_slots(child_mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return occupied, offs
 
 
-def decode_meta_rows(meta, meta_format: str, level, pcode=None):
-    """In-register dequantize of gathered packed metadata rows.
+def decode_meta_words(words, meta_format: str, level, pcode=None):
+    """In-register dequantize of gathered packed metadata words.
 
     Shared by the jnp ref arm and the Pallas megakernel (identical jnp
-    ops on the same int words -> bitwise-identical geometry and
-    topology across formats).  ``meta`` is the (w, words) int32 gather
-    for one level; ``pcode`` is the frontier's carried parent-code lane
-    (u8 format only — the row stores just the child's octant).
+    ops on the same int words -> bitwise-identical geometry and topology
+    across formats).  ``words`` is a sequence of the row's int32 words,
+    each an array of one common shape (the ref passes (w,) columns of a
+    row gather, the kernel (1, C) lane rows); ``pcode`` is the frontier's
+    carried parent-code lane (u8 format only — the row stores just the
+    child's octant).
 
     Returns ``(xyz, full, child_start, child_mask, code_own)`` where
-    ``xyz`` is (w, 3) int32 cell coordinates at ``level`` and
+    ``xyz`` is the three int32 cell-coordinate arrays at ``level`` and
     ``code_own`` the lane's own Morton code (int32; only meaningful —
     and only used — under ``meta_format="u8"``, where children inherit
     it as their ``pcode``).
     """
+    w0 = words[0]
+    zero = jnp.zeros_like(w0)
     if meta_format == "fp32":
-        codes = jax.lax.bitcast_convert_type(meta[:, 0], jnp.uint32)
-        full_l = meta[:, 1] != 0
-        child_start = meta[:, 2]
-        child_mask = meta[:, 3]
-        return (jnp_morton_decode(codes), full_l, child_start, child_mask,
-                jnp.zeros(meta.shape[:1], jnp.int32))
-    w0 = meta[:, 0]
+        code = w0
+        xyz = [_jnp_compact1by2(code >> k).astype(jnp.int32)
+               for k in range(3)]
+        return xyz, words[1] != 0, words[2], words[3], zero
     # Topology word: full << 31 | [octant << 28 |] child_start << 8 | mask.
     # w0 >> k is an arithmetic shift (sign-extends when full is set); the
     # field masks strip the extension bits.
@@ -125,20 +127,20 @@ def decode_meta_rows(meta, meta_format: str, level, pcode=None):
     child_mask = w0 & 0xFF
     if meta_format == "bf16":
         child_start = (w0 >> 8) & ((1 << BF16_START_BITS) - 1)
-        w1 = meta[:, 1]
+        w1 = words[1]
         # Geometry word: 3 x 10-bit leaf-grid coords; a level-l cell
         # coordinate is the field shifted back down (exact by packing).
         shift = jnp.int32(GRID_BITS) - level
-        xyz = jnp.stack([((w1 >> 20) & 0x3FF) >> shift,
-                         ((w1 >> 10) & 0x3FF) >> shift,
-                         (w1 & 0x3FF) >> shift], axis=-1)
-        return xyz, full_l, child_start, child_mask, \
-            jnp.zeros(meta.shape[:1], jnp.int32)
+        xyz = [((w1 >> 20) & 0x3FF) >> shift,
+               ((w1 >> 10) & 0x3FF) >> shift,
+               (w1 & 0x3FF) >> shift]
+        return xyz, full_l, child_start, child_mask, zero
     assert meta_format == "u8" and pcode is not None, \
         f"unknown meta_format {meta_format!r}; allowed: {META_FORMATS}"
     child_start = (w0 >> 8) & ((1 << U8_START_BITS) - 1)
     code_own = (pcode << 3) | ((w0 >> 28) & 7)
-    xyz = jnp_morton_decode(code_own.astype(jnp.uint32))
+    xyz = [_jnp_compact1by2(code_own >> k).astype(jnp.int32)
+           for k in range(3)]
     return xyz, full_l, child_start, child_mask, code_own
 
 
@@ -254,8 +256,10 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                     >> 3)
             else:
                 pcode = None
-            xyz, full_l, child_start, child_mask, code_own = decode_meta_rows(
-                meta, meta_format, level, pcode)
+            xyz, full_l, child_start, child_mask, code_own = \
+                decode_meta_words([meta[:, k] for k in range(meta.shape[1])],
+                                  meta_format, level, pcode)
+            xyz = jnp.stack(xyz, axis=-1)
             is_leaf = level == depth
 
             if ragged:
@@ -308,7 +312,7 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                 # A kernel tile fetches window w of ITS scene's sub-extent
                 # at this level iff some valid lane of the tile points into
                 # it; the fetched span is the window's occupied extent
-                # rounded out to whole 8-row DMA chunks.
+                # rounded out to whole META_ROW_ALIGN-row DMA chunks.
                 off_l = jax.lax.dynamic_index_in_dim(
                     scene_off, level, axis=1, keepdims=False)       # (S,)
                 cnt_l = jax.lax.dynamic_index_in_dim(
@@ -326,8 +330,9 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                 occ = jnp.clip(cnt_t - wlo, 0, stream_wsub)
                 g_lo = off_t + wlo
                 g_hi = g_lo + occ
+                a = META_ROW_ALIGN
                 span = jnp.where(occ > 0,
-                                 (-(-g_hi // 8)) * 8 - (g_lo // 8) * 8, 0)
+                                 (-(-g_hi // a)) * a - (g_lo // a) * a, 0)
                 meta_rows = st["meta_rows"] + jnp.sum(live * span)
             else:
                 meta_rows = st["meta_rows"]

@@ -101,9 +101,17 @@ def sact_tile(t, Rb, A, ahb, ohb, *, use_spheres: bool):
                                                   sep, 8 + 3 * i + j)
         return decided_sep, exit_code
 
-    all_decided = jnp.all(decided_sep | confirmed_hit)
-    decided_sep, exit_code = jax.lax.cond(
-        all_decided, lambda d, e: (d, e), edge_stage, decided_sep, exit_code)
+    # The cond carries the decided mask as int32: Mosaic cannot return
+    # boolean vectors from a branch.
+    def edge_stage_i32(decided, exit_code):
+        d, e = edge_stage(decided != 0, exit_code)
+        return d.astype(jnp.int32), e
+
+    all_decided = jnp.sum(jnp.where(decided_sep | confirmed_hit, 0, 1)) == 0
+    decided_i32, exit_code = jax.lax.cond(
+        all_decided, lambda d, e: (d, e), edge_stage_i32,
+        decided_sep.astype(jnp.int32), exit_code)
+    decided_sep = decided_i32 != 0
 
     collide = (~decided_sep) | confirmed_hit
     return collide, exit_code

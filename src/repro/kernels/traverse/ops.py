@@ -8,10 +8,10 @@ unfused device arm, which materializes ~5 capacity-sized arrays per level
 (the 4-field SactResult, two searchsorted probe vectors, the 8x-expanded
 candidate codes, and the compaction scratch).
 
-The staged test dispatches like :mod:`repro.kernels.compact`: the Pallas
-traversal-step kernel on TPU (or ``interpret=True`` for CPU validation —
-untenable inside real traversals because interpret mode unrolls one program
-per grid step at trace time), and the jnp two-phase reference elsewhere.
+The staged test dispatches on the backend: the Pallas traversal-step
+kernel on TPU (or ``interpret=True`` for CPU validation — untenable inside
+real traversals because interpret mode unrolls one program per grid step
+at trace time), and the jnp two-phase reference elsewhere.
 Both arms share this glue, so verdicts, exit codes, and the CSR expansion
 are backend-independent; and both cull in two phases — spheres + box-normal
 axes decide most pairs, the edge axes run only when survivors remain
@@ -41,7 +41,7 @@ from repro.core.sact import (SactResult, axis_tests_from_exit,
 from repro.kernels.compact.ops import compact_pairs
 from repro.kernels.persist.ref import csr_child_slots
 from repro.kernels.sact.ops import pack_obbs
-from repro.kernels.traverse.kernel import make_traverse_call
+from repro.kernels.traverse.kernel import LANES, make_traverse_call
 from repro.kernels.traverse.ref import unpack_verdicts
 
 
@@ -52,28 +52,34 @@ def _use_pallas_default() -> bool:
 def _test_pallas(obb_c, obb_h, obb_r, q_idx, codes, full_l, cell, scene_lo,
                  is_leaf, n_live, use_spheres: bool, bn: int,
                  interpret: bool):
-    """Pallas arm: packed verdict words for the whole frontier."""
+    """Pallas arm: packed verdict words for the whole frontier.
+
+    The query OBBs are gathered here, by XLA, into the kernel's
+    field-major lane-dense slab: a VMEM-resident OBB table with an
+    in-kernel one-hot gather does not scale to paper-size batches.
+    """
     capacity = q_idx.shape[0]
     pad = (-capacity) % bn
-    obb = pack_obbs(obb_c, obb_h, obb_r)
+    rows = (capacity + pad) // LANES
+    obb = pack_obbs(obb_c, obb_h, obb_r)[
+        jnp.clip(q_idx, 0, obb_c.shape[0] - 1)]
+    obb = jnp.pad(obb, ((0, pad), (0, 0))).T.reshape(15, rows, LANES)
+    lanes = jnp.stack([
+        jnp.pad(jax.lax.bitcast_convert_type(codes, jnp.int32), (0, pad)),
+        jnp.pad(full_l.astype(jnp.int32), (0, pad))]).reshape(2, rows, LANES)
     scal_i = jnp.stack([jnp.asarray(n_live, jnp.int32),
                         jnp.asarray(is_leaf, jnp.int32)])
     scal_f = jnp.concatenate([jnp.asarray(cell, jnp.float32).reshape(1),
                               jnp.asarray(scene_lo, jnp.float32)])
-    call = make_traverse_call(capacity + pad, obb.shape[0], bn, use_spheres,
-                              interpret)
-    packed = call(scal_i, scal_f, obb,
-                  jnp.pad(q_idx.astype(jnp.int32), (0, pad)),
-                  jnp.pad(codes, (0, pad)),
-                  jnp.pad(full_l.astype(jnp.int32), (0, pad)))
-    return packed[:capacity]
+    call = make_traverse_call(capacity + pad, bn, use_spheres, interpret)
+    packed = call(scal_i, scal_f, obb, lanes)
+    return packed.reshape(-1)[:capacity]
 
 
 def traverse_step(obb_c, obb_h, obb_r, dev: DeviceOctree, level, n_live,
                   q_idx, node_idx, verdict, *, use_spheres: bool,
                   use_pallas: Optional[bool] = None,
-                  use_pallas_compact: Optional[bool] = None,
-                  interpret: Optional[bool] = None, bn: int = 256,
+                  interpret: Optional[bool] = None, bn: int = 1024,
                   owner=None, payload=None
                   ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                              dict]:
@@ -169,8 +175,7 @@ def traverse_step(obb_c, obb_h, obb_r, dev: DeviceOctree, level, n_live,
     n_new = jnp.sum(child_live.astype(jnp.int32))
     cnt, q_next, idx_next = compact_pairs(
         child_live, jnp.repeat(q_idx, 8),
-        cand_idx.reshape(-1).astype(jnp.uint32), capacity,
-        use_pallas=use_pallas_compact)
+        cand_idx.reshape(-1).astype(jnp.uint32), capacity)
     idx_next = idx_next.astype(jnp.int32)
     info = dict(valid=valid, is_term=is_term, res=res, codes=codes,
                 n_new=n_new)

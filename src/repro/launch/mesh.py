@@ -24,12 +24,5 @@ def make_host_mesh(model: int = 1):
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    jax >= 0.5 spells this ``jax.set_mesh``; on older versions the Mesh
-    object itself is the context manager.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)

@@ -39,6 +39,7 @@ from repro.engine.batcher import (RequestBatcher, RequestStats, ServiceError,
 from repro.engine.executor import CollisionEngine, EngineConfig
 from repro.engine.faults import FaultPlan, FaultyEngine, poison_obbs
 from repro.engine.plan import PlanValidationError, plan_queries
+from repro.launch.compile_cache import setup_compile_cache
 
 #: SLO quantities the harness reports (drift-guarded against the
 #: DESIGN.md §6 SLO table): client-observed latency percentiles over
@@ -264,6 +265,7 @@ def main() -> None:
                          "until this much wall time has elapsed; reports "
                          "aggregate per-pass reliability counters")
     args = ap.parse_args()
+    setup_compile_cache()
     deadline_ms = args.deadline_ms
     launch_timeout_s = args.launch_timeout_s
     if args.chaos:

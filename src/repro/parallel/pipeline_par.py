@@ -16,7 +16,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

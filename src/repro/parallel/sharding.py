@@ -23,12 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
-# jax >= 0.5 promotes shard_map to the top level; fall back to experimental.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
-
 STACK_KEYS = ("blocks", "enc_blocks", "dec_blocks")
 
 
@@ -313,7 +307,7 @@ def shard_collision_traversal(fn, mesh: Mesh):
     and returns the still-sharded verdict plus the reduced stats with a
     leading shard axis of identical rows (the traversal's ``while_loop``
     has no shard_map replication rule, so the wrapper runs with
-    ``check_rep=False`` and cannot declare replicated ``P()`` outputs —
+    ``check_vma=False`` and cannot declare replicated ``P()`` outputs —
     callers read row 0).
     """
     axis = COLLISION_AXIS
@@ -325,6 +319,6 @@ def shard_collision_traversal(fn, mesh: Mesh):
                for k, v in st.items()}
         return verdict, red
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
-                     out_specs=(P(axis), P(axis)), check_rep=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
+                         out_specs=(P(axis), P(axis)), check_vma=False)

@@ -120,7 +120,7 @@ def test_compressed_psum_error_feedback():
         mean, new_r = compressed_psum_tree({"w": g[0]}, {"w": r[0]}, "data")
         return mean["w"], new_r["w"]
 
-    from repro.parallel.sharding import shard_map
+    from jax import shard_map
     sm = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
                        out_specs=(P(), P("data")))
     r = jnp.zeros((8, 64))

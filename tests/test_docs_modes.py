@@ -159,7 +159,7 @@ def test_design_window_constants_match_code():
     text = _flat_text("DESIGN.md")
     assert f"`SUB_WINDOW_ROWS` = {SUB_WINDOW_ROWS}" in text, \
         "DESIGN.md §3 window-schedule bullet disagrees with SUB_WINDOW_ROWS"
-    assert "2 * (SUB_WINDOW_ROWS + 8)" in text, \
+    assert "2 * (SUB_WINDOW_ROWS + 128)" in text, \
         "DESIGN.md no longer states the constant ping/pong VMEM footprint"
     assert f"`MAX_TILE_BQ` = {MAX_TILE_BQ}" in text, \
         "DESIGN.md §3 owner-tiling paragraph disagrees with MAX_TILE_BQ"

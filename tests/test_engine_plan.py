@@ -173,9 +173,10 @@ def test_traverse_step_payload_lane_interpret_matches_ref(use_spheres):
     G = 6
     owner = jnp.asarray(rs.randint(0, G, obbs.n).astype(np.int32))
     payload = jnp.asarray(rs.randint(0, 100, obbs.n).astype(np.int32))
-    level, cap = 2, 96
+    # Three kernel blocks of 1024 lanes: two live, the third retired.
+    level, cap = 2, 3 * 1024
     n_l = len(tree.levels[level].codes)
-    n_live = min(cap, max(n_l, 8))
+    n_live = 1024 + 300
     idx = jnp.asarray(rs.randint(0, n_l, cap).astype(np.int32))
     q = jnp.asarray(rs.randint(0, obbs.n, cap).astype(np.int32))
     best0 = jnp.full((obbs.n,), PAYLOAD_INF, jnp.int32)
@@ -183,7 +184,7 @@ def test_traverse_step_payload_lane_interpret_matches_ref(use_spheres):
             jnp.int32(n_live), q, idx, best0)
     kw = dict(use_spheres=use_spheres, owner=owner, payload=payload)
     ref = traverse_step(*args, use_pallas=False, **kw)
-    pal = traverse_step(*args, use_pallas=True, interpret=True, bn=32, **kw)
+    pal = traverse_step(*args, use_pallas=True, interpret=True, bn=1024, **kw)
     for name, a, b in zip(("cnt", "q_next", "idx_next", "best"),
                           ref[:4], pal[:4]):
         assert bool(jnp.all(a == b)), name

@@ -182,3 +182,15 @@ def test_scene_traversal_on_synthetic_cubby():
     got, c = CollisionEngine(tree, EngineConfig(mode="wavefront")).query(obbs)
     assert (got == ref).all()
     assert 0 < int(ref.sum()) < obbs.n           # some but not all collide
+
+
+def test_scene_is_fixed_by_its_seed():
+    """make_scene derives its RNG seed from the environment's index, not
+    from Python's per-process salted string hash: the same seed gives the
+    same points in every process."""
+    import hashlib
+    pts = make_scene("cubby", 0, 4096).points
+    assert hashlib.sha256(pts.tobytes()).hexdigest() == (
+        "92fd5ddfafc9ac06a8d313056a6a9dd925283451b94e6e4bd0fea23b24daa3cf")
+    with pytest.raises(ValueError):
+        make_scene("no_such_scene", 0, 16)

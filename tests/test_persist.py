@@ -1,10 +1,10 @@
 """Persistent whole-traversal megakernel: engine equivalence, interpret-mode
-kernel vs ref, spill ring, ragged multi-scene frontier, escalation policy,
+kernel vs ref, overflow counting, ragged multi-scene frontier, escalation policy,
 and the traversal jit cache.
 
 The Pallas megakernel runs under ``interpret=True`` here so the CPU CI
 matrix exercises the kernel body without a TPU, mirroring the
-kernels/compact and kernels/traverse setups.
+kernels/traverse setup.
 """
 import numpy as np
 import jax
@@ -83,9 +83,9 @@ def test_persist_kernel_interpret_matches_ref(use_spheres):
 
 
 def test_persist_kernel_spill_ring_counts_overflow():
-    """A deliberately tiny VMEM frontier must spill: the kernel reports the
-    same overflow count as the global-pool ref (single tile == one pool)
-    and records spilled pairs in the HBM ring."""
+    """A deliberately tiny VMEM frontier must overflow: the kernel reports
+    the same overflow count as the global-pool ref (single tile == one
+    pool)."""
     rs = np.random.RandomState(3)
     pts = rs.uniform(-1, 1, (4000, 3)).astype(np.float32)
     tree = build_octree(pts, depth=4)
@@ -120,6 +120,48 @@ def test_persistent_escalation_replays_until_exact():
     got2, c2 = eng.query(obbs)
     assert (got2 == ref).all()
     assert c2.escalations == 0
+
+
+@pytest.fixture
+def tile_frontier_cap(monkeypatch):
+    """Lower the megakernel's per-tile frontier cap to 256 lanes; the
+    traversal cache is cleared around the test so no program traced at
+    the real cap is reused, and none traced at 256 outlives it."""
+    from repro.engine import executor
+    from repro.kernels.persist import ops
+    executor._traversal_fn.cache_clear()
+    monkeypatch.setattr(ops, "MAX_TILE_FRONTIER", 256)
+    yield 256
+    executor._traversal_fn.cache_clear()
+
+
+def test_tile_frontier_cap_routes_to_ref_arm_exactly(tile_frontier_cap,
+                                                     caplog):
+    """A tile whose frontier outgrows the megakernel's per-tile cap cannot
+    be helped by more capacity: the engine finishes the ladder on the ref
+    arm (warning + ``ref_arm_fallbacks``), with zero overflow and verdicts
+    and work counters equal to the ref arm's own run.  A repeat plan
+    starts on the ref arm."""
+    rs = np.random.RandomState(2)
+    pts = rs.uniform(-1, 1, (8000, 3)).astype(np.float32)
+    tree = build_octree(pts, depth=4)
+    obbs = random_obbs(jax.random.PRNGKey(3), 40)   # peak frontier 1088
+    naive, _ = CollisionEngine(tree, EngineConfig(mode="naive")).query(obbs)
+    ref, ref_c = CollisionEngine(tree, EngineConfig(
+        mode="wavefront_persistent", min_bucket=32,
+        use_pallas_traverse=False)).query(obbs)
+    eng = CollisionEngine(tree, EngineConfig(
+        mode="wavefront_persistent", min_bucket=32,
+        use_pallas_traverse=True))
+    with caplog.at_level("WARNING", logger="repro.engine.executor"):
+        got, c = eng.query(obbs)
+    assert "tile frontier" in caplog.text
+    assert c.ref_arm_fallbacks == 1 and c.frontier_overflow == 0
+    assert (got == ref).all() and (got == naive).all()
+    _assert_counters_equal(c, ref_c, "tile-frontier fallback")
+    got2, c2 = eng.query(obbs)
+    assert (got2 == ref).all()
+    assert c2.ref_arm_fallbacks == 1 and c2.escalations == 0
 
 
 def test_persistent_max_frontier_clamp_underapproximates():

@@ -42,6 +42,21 @@ def _assert_counters_equal(c0, c1, ctx):
             (ctx, k, d0[k], d1[k])
 
 
+def test_sharded_persistent_over_vmem_budget_raises_before_compile():
+    """Sharded persistent runs pin the resident fp32 table; a scene whose
+    table exceeds the VMEM budget is refused with a ValueError naming the
+    budget, before anything is traced or compiled."""
+    from repro.engine.executor import traversal_cache_info
+    tree = _tree(0)
+    eng = CollisionEngine(tree, EngineConfig(
+        mode="wavefront_persistent", shards=1, use_pallas_traverse=True,
+        vmem_budget=1024))
+    before = traversal_cache_info()
+    with pytest.raises(ValueError, match="VMEM"):
+        eng.execute(plan_queries(random_obbs(jax.random.PRNGKey(1), 8)))
+    assert traversal_cache_info() == before
+
+
 @pytest.mark.parametrize("mode", ["wavefront", "wavefront_fused",
                                   "wavefront_persistent"])
 def test_sharded_one_shard_matches_single_device(mode):

@@ -1,8 +1,7 @@
 """Fused traversal step: CSR child table, kernel-vs-ref, engine equivalence.
 
 The Pallas traversal-step kernel runs under ``interpret=True`` here so the
-CPU CI matrix exercises kernel changes without a TPU, mirroring the
-kernels/compact setup.
+CPU CI matrix exercises kernel changes without a TPU.
 """
 import numpy as np
 import jax
@@ -82,10 +81,12 @@ def test_two_phase_sact_matches_one_shot(seed):
 
 
 @pytest.mark.parametrize("use_spheres", [False, True])
-@pytest.mark.parametrize("bn", [32])
+@pytest.mark.parametrize("bn", [1024])
 def test_traverse_kernel_interpret_matches_ref(use_spheres, bn):
     """Pallas traversal-step kernel (interpret=True) == jnp reference arm:
-    packed verdicts, compacted next frontier, and work-model fields."""
+    packed verdicts, compacted next frontier, and work-model fields.  The
+    frontier spans three ``bn`` blocks: the shallow levels leave the later
+    blocks retired (past ``n_live``), the leaf level keeps all three live."""
     rs = np.random.RandomState(bn)
     pts = rs.uniform(-1, 1, (3000, 3)).astype(np.float32)
     tree = build_octree(pts, depth=4)
@@ -93,7 +94,7 @@ def test_traverse_kernel_interpret_matches_ref(use_spheres, bn):
     obbs = random_obbs(jax.random.PRNGKey(bn), 24)
     for level in (1, 2, tree.depth):
         n_l = len(tree.levels[level].codes)
-        cap = 96
+        cap = 3 * bn
         n_live = min(cap, max(n_l, 8))
         idx = rs.randint(0, n_l, cap).astype(np.int32)
         q = rs.randint(0, obbs.n, cap).astype(np.int32)
@@ -115,14 +116,16 @@ def test_traverse_kernel_interpret_matches_ref(use_spheres, bn):
 
 
 def test_traverse_packed_words_kernel_vs_ref_oracle():
-    """The raw pallas_call's packed verdict words == the jnp oracle's."""
+    """The raw pallas_call's packed verdict words == the jnp oracle's, on
+    a three-block frontier with two live blocks and one retired."""
     rs = np.random.RandomState(5)
     pts = rs.uniform(-1, 1, (2000, 3)).astype(np.float32)
     tree = build_octree(pts, depth=3)
     obbs = random_obbs(jax.random.PRNGKey(5), 16)
-    level, cap = 2, 64
+    bn = 1024
+    level, cap = 2, 3 * bn
     n_l = len(tree.levels[level].codes)
-    n_live = min(cap, n_l)
+    n_live = bn + 300
     idx = rs.randint(0, n_l, cap)
     codes = jnp.asarray(tree.levels[level].codes[idx])
     full = jnp.asarray(tree.levels[level].full[idx])
@@ -135,7 +138,7 @@ def test_traverse_packed_words_kernel_vs_ref_oracle():
                                    use_spheres=False)
     pal_packed = traverse_ops._test_pallas(
         obbs.center, obbs.half, obbs.rot, q, codes, full, cell, lo,
-        jnp.bool_(False), jnp.int32(n_live), False, bn=32, interpret=True)
+        jnp.bool_(False), jnp.int32(n_live), False, bn=bn, interpret=True)
     assert bool(jnp.all(ref_packed == pal_packed))
 
 
@@ -184,3 +187,24 @@ def test_fused_engine_batched_and_spheres():
     assert (a == b).all()
     assert cb.sphere_tests > 0
     assert cb.axis_tests_executed <= ca.axis_tests_executed
+
+
+def test_fused_engine_kernel_arm_matches_ref_arm():
+    """The fused engine with its Pallas step forced on (interpret=True)
+    == the same engine on the jnp step: verdicts and every work counter.
+    The pinned 3,072-lane frontier is three kernel blocks, so levels run
+    with retired blocks and with several live ones."""
+    rs = np.random.RandomState(2)
+    pts = rs.uniform(-1, 1, (8000, 3)).astype(np.float32)
+    tree = build_octree(pts, depth=4)
+    obbs = random_obbs(jax.random.PRNGKey(3), 40)
+    res = {arm: CollisionEngine(tree, EngineConfig(
+        mode="wavefront_fused", frontier_capacity=3 * 1024,
+        use_pallas_traverse=arm)).query(obbs) for arm in (False, True)}
+    (ref_col, ref_c), (col, c) = res[False], res[True]
+    assert (col == ref_col).all()
+    assert c.frontier_overflow == 0 and max(c.nodes_per_level) > 1024
+    for f in WORK_FIELDS:
+        assert getattr(c, f) == getattr(ref_c, f), f
+    assert c.nodes_per_level == ref_c.nodes_per_level
+    assert (c.exit_histogram == ref_c.exit_histogram).all()
