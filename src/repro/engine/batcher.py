@@ -77,12 +77,23 @@ Per-request latency accounting (:class:`RequestStats`): ``wait_s`` is
 admission (submit -> launch), ``exec_s`` the shared engine call,
 ``total_s`` their sum — the quantities the serve harness turns into
 p50/p99 SLO rows — plus the reliability fields ``retries`` (transient
-relaunches the request rode through) and ``splits`` (bisect depth).
+relaunches the request rode through) and ``splits`` (bisect depth), and
+``request_id`` / ``launch_id``, which join a request to its spans.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, recorded only while a
+profiler trace is active): ``batcher.submit`` (attribute ``request``),
+``batcher.coalesce`` (the ``max_wait_ms`` window, ``requests``),
+``batcher.launch`` (``launch``, ``requests``, ``live``, ``pad``,
+``depth``; bisect halves nest inside), ``batcher.pool`` (concatenate and
+pad, ``live``, ``pad``) and ``batcher.resolve`` (``requests``).  The
+engine's own ``engine.execute`` / ``executor.*`` spans nest inside
+``batcher.launch``.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import logging
 import queue
 import threading
@@ -91,6 +102,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.counters import Counters
 from repro.core.geometry import OBBs
@@ -160,6 +172,8 @@ class RequestStats:
     #                         bucket + depth-capped traversal): verdicts
     #                         are a conservative superset — no silent
     #                         quality loss, the response says what it is
+    request_id: int = -1   # the batcher's sequence number of the submit
+    launch_id: int = -1    # sequence number of the launch that carried it
 
 
 class BatchTicket:
@@ -235,6 +249,7 @@ class _Pending:
     t_submit: float
     t_deadline: Optional[float] = None   # absolute perf_counter deadline
     work: int = 0                        # predicted work units (admission)
+    request_id: int = -1
 
 
 @dataclasses.dataclass
@@ -327,6 +342,10 @@ class RequestBatcher:
         #: shards_lost/shard_rescales/degraded_launches).
         self.totals = Counters()
         self.num_launches = 0
+        # Sequence numbers of submits and launches (span attributes and
+        # RequestStats.request_id / launch_id).
+        self._request_seq = itertools.count()
+        self._launch_seq = itertools.count()
         self._queue: "queue.Queue" = queue.Queue()
         self._lock = threading.Lock()
         self._closed = False
@@ -386,6 +405,13 @@ class RequestBatcher:
         :class:`repro.engine.plan.PlanValidationError` for malformed
         plans — all before the request can touch a shared launch.
         """
+        request_id = next(self._request_seq)
+        with TraceAnnotation("batcher.submit", request=request_id):
+            return self._submit(plan_or_obbs, deadline_ms, validate,
+                                request_id)
+
+    def _submit(self, plan_or_obbs, deadline_ms: Optional[float],
+                validate: bool, request_id: int) -> BatchTicket:
         t_submit = time.perf_counter()
         plan = (plan_queries(plan_or_obbs)
                 if isinstance(plan_or_obbs, OBBs) else plan_or_obbs)
@@ -429,7 +455,8 @@ class RequestBatcher:
                     f"max_queue_work={self.max_queue_work} — shedding")
         deadline = (None if deadline_ms is None
                     else t_submit + deadline_ms / 1e3)
-        pending = _Pending(plan, BatchTicket(), t_submit, deadline, work)
+        pending = _Pending(plan, BatchTicket(), t_submit, deadline, work,
+                           request_id)
         with self._lock:
             self._queued_work += work
         self._queue.put(pending)
@@ -591,27 +618,29 @@ class RequestBatcher:
             deadline = time.perf_counter() + self.max_wait_s
             stop = False
             rebind = None
-            while total < self.max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is _STOP:
-                    stop = True
-                    break
-                if isinstance(nxt, _Rebind):
-                    # Stop coalescing: requests queued BEFORE the rebind
-                    # launch against the old scene first (FIFO), then the
-                    # swap applies.
-                    rebind = nxt
-                    break
-                with self._lock:
-                    self._queued_work -= nxt.work
-                batch.append(nxt)
-                total += nxt.plan.num_queries
+            with TraceAnnotation("batcher.coalesce") as span:
+                while total < self.max_batch:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        nxt = self._queue.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if nxt is _STOP:
+                        stop = True
+                        break
+                    if isinstance(nxt, _Rebind):
+                        # Stop coalescing: requests queued BEFORE the
+                        # rebind launch against the old scene first
+                        # (FIFO), then the swap applies.
+                        rebind = nxt
+                        break
+                    with self._lock:
+                        self._queued_work -= nxt.work
+                    batch.append(nxt)
+                    total += nxt.plan.num_queries
+                span.set_metadata(requests=len(batch))
             self._admit(batch)
             if rebind is not None:
                 self._do_rebind(rebind)
@@ -745,17 +774,18 @@ class RequestBatcher:
         retries = 0
         while True:
             pad = bucket - live
-            cc, hh, rr = list(c), list(h), list(r)
-            if pad:
-                po = self._pad_obbs(pad)
-                cc.append(np.asarray(po.center))
-                hh.append(np.asarray(po.half))
-                rr.append(np.asarray(po.rot))
-            pool = OBBs(center=np.concatenate(cc), half=np.concatenate(hh),
-                        rot=np.concatenate(rr))
+            with TraceAnnotation("batcher.pool", live=live, pad=pad):
+                cc, hh, rr = list(c), list(h), list(r)
+                if pad:
+                    po = self._pad_obbs(pad)
+                    cc.append(np.asarray(po.center))
+                    hh.append(np.asarray(po.half))
+                    rr.append(np.asarray(po.rot))
+                pool = plan_queries(OBBs(center=np.concatenate(cc),
+                                         half=np.concatenate(hh),
+                                         rot=np.concatenate(rr)))
             try:
-                verdict, counters = self._call_engine(plan_queries(pool),
-                                                      max_depth)
+                verdict, counters = self._call_engine(pool, max_depth)
                 return verdict, counters, live, pad, retries
             except BaseException as e:            # noqa: BLE001
                 if not _is_transient(e) or retries >= self.max_retries:
@@ -771,6 +801,13 @@ class RequestBatcher:
         """Launch one coalesced batch; on failure, bisect-retry so only
         the poisoned request's ticket errors while innocent co-riders
         complete (fault isolation, DESIGN.md §7)."""
+        launch_id = next(self._launch_seq)
+        with TraceAnnotation("batcher.launch", launch=launch_id,
+                             requests=len(batch), depth=depth) as span:
+            self._launch_once(batch, depth, launch_id, span)
+
+    def _launch_once(self, batch: List[_Pending], depth: int,
+                     launch_id: int, span: TraceAnnotation):
         t_launch = time.perf_counter()
         for p in batch:
             p.ticket._mark_launched()
@@ -780,6 +817,7 @@ class RequestBatcher:
         try:
             verdict, counters, live, pad, retries = \
                 self._execute_with_retry(batch, degraded)
+            span.set_metadata(live=live, pad=pad)
             counters.pad_queries += pad
             if degraded:
                 counters.degraded_launches += 1
@@ -796,20 +834,22 @@ class RequestBatcher:
                 self._work_rate = (
                     rate if self._work_rate is None
                     else 0.5 * self._work_rate + 0.5 * rate)
-            off = 0
-            for p in batch:
-                q = p.plan.num_queries
-                stats = RequestStats(
-                    wait_s=t_launch - p.t_submit,
-                    exec_s=exec_s,
-                    total_s=t_done - p.t_submit,
-                    batch_requests=len(batch), batch_queries=live,
-                    pad_queries=pad, retries=retries, splits=depth,
-                    degraded=degraded)
-                p.ticket._resolve(p.plan.unflatten(verdict[off:off + q]),
-                                  stats)
-                self._lat_window.append(stats.total_s)
-                off += q
+            with TraceAnnotation("batcher.resolve", requests=len(batch)):
+                off = 0
+                for p in batch:
+                    q = p.plan.num_queries
+                    stats = RequestStats(
+                        wait_s=t_launch - p.t_submit,
+                        exec_s=exec_s,
+                        total_s=t_done - p.t_submit,
+                        batch_requests=len(batch), batch_queries=live,
+                        pad_queries=pad, retries=retries, splits=depth,
+                        degraded=degraded, request_id=p.request_id,
+                        launch_id=launch_id)
+                    p.ticket._resolve(
+                        p.plan.unflatten(verdict[off:off + q]), stats)
+                    self._lat_window.append(stats.total_s)
+                    off += q
             if depth == 0:
                 self._maybe_rescale()
         except BaseException as e:                    # noqa: BLE001

@@ -21,6 +21,14 @@ downstream of the lowering, for every plan alike:
   * **counter assembly** — device-side stats become
     :class:`repro.core.counters.Counters`, including the §4 bytes model.
 
+Profiler spans (``jax.profiler.TraceAnnotation``, recorded only while a
+profiler trace is active) mark the executor's phases on the host plane,
+which shares its clock with the device trace: ``engine.execute`` around a
+call, ``executor.stage`` (the plan's host arrays placed on the device),
+``executor.dispatch`` (one jitted traversal call per escalation rung) and
+``executor.sync`` (each blocking device-to-host read: ``overflow``,
+``counters``, ``verdict``).  They add no sync and no device work.
+
 Verdict state generalizes from a boolean per query to an int32 ``best``
 per *verdict group* (``PAYLOAD_INF`` = undecided): a terminal hit folds
 the pair's payload lane in with a min, and a pair expands only while its
@@ -46,6 +54,7 @@ from typing import List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import sact as sact_mod
 from repro.core.counters import (BYTES_FUSED_STEP, BYTES_FUSED_TEST,
@@ -237,13 +246,20 @@ def _escalate(run, num_queries: int, worst: int, cfg: EngineConfig,
         cap = min(max(start, cap), max(worst, num_queries))
     replays = 0
     while True:
-        verdict, st = run(cap)
+        with TraceAnnotation("executor.dispatch", capacity=cap, rung=replays):
+            verdict, st = run(cap)
         if cfg.frontier_capacity is not None or cap >= worst:
             return verdict, st, cap, replays
-        if int(jax.device_get(jnp.sum(st["overflow"]))) == 0:
+        if _overflowed(st) == 0:
             return verdict, st, cap, replays
         cap = min(max(cap * 4, cfg.min_bucket), worst)
         replays += 1
+
+
+def _overflowed(st) -> int:
+    """Frontier overflow of a finished traversal (a blocking read)."""
+    with TraceAnnotation("executor.sync", what="overflow"):
+        return int(jax.device_get(jnp.sum(st["overflow"])))
 
 
 def _tile_frontier_guard(run_arm, memo: dict, memo_key, tag: str):
@@ -266,8 +282,7 @@ def _tile_frontier_guard(run_arm, memo: dict, memo_key, tag: str):
     def run(cap):
         if not on_ref[0]:
             verdict, st = run_arm(cap, True)
-            if (cap < persist_ops.MAX_TILE_FRONTIER
-                    or int(jax.device_get(jnp.sum(st["overflow"]))) == 0):
+            if cap < persist_ops.MAX_TILE_FRONTIER or _overflowed(st) == 0:
                 return verdict, st
             logger.warning(
                 "persistent plan %s overflows the megakernel's %d-lane "
@@ -515,30 +530,30 @@ def _traversal_fn(mode: str, batch: str, capacity: int, use_spheres: bool,
 
     def base(c, h, r, d, soq=None, owner=None, payload=None, tiles=None):
         _TRACE_COUNTS[key] = _TRACE_COUNTS.get(key, 0) + 1
-        if mode == "wavefront_persistent" or soq is not None or \
-                tiles is not None:
-            assert max_depth is None, \
-                "the persistent/ragged arms have no depth cap (DESIGN.md §7)"
-            # Whole-traversal megakernel / live-prefix ref; the ragged
-            # multi-scene flat frontier (soq or a pre-built tile map)
-            # also lands here for every CSR mode.  Only the persistent
-            # mode may take the megakernel arm — the fused mode's ragged
-            # pool is ref-served so its counters stay the per-level
-            # arm's (its own Pallas kernel is the per-level step).
-            return traverse_whole(c, h, r, d, capacity,
-                                  use_spheres=use_spheres,
-                                  use_pallas=(use_pallas_traverse
-                                              if mode == "wavefront_persistent"
-                                              else False),
-                                  scene_of_query=soq, owner_of_query=owner,
-                                  payload=payload, streamed=streamed,
-                                  tiles=tiles)
-        if mode == "wavefront_fused":
-            return _traverse_fused(c, h, r, d, capacity, use_spheres,
-                                   use_pallas_traverse, owner=owner,
-                                   payload=payload, max_depth=max_depth)
-        return _traverse(c, h, r, d, capacity, use_spheres, owner=owner,
-                         payload=payload, max_depth=max_depth)
+        with jax.named_scope("collide_traversal"):
+            if mode == "wavefront_persistent" or soq is not None or \
+                    tiles is not None:
+                assert max_depth is None, ("the persistent/ragged arms "
+                                           "have no depth cap (DESIGN.md §7)")
+                # Whole-traversal megakernel / live-prefix ref; the ragged
+                # multi-scene flat frontier (soq or a pre-built tile map)
+                # also lands here for every CSR mode.  Only the persistent
+                # mode may take the megakernel arm — the fused mode's ragged
+                # pool is ref-served so its counters stay the per-level
+                # arm's (its own Pallas kernel is the per-level step).
+                kernel = (use_pallas_traverse
+                          if mode == "wavefront_persistent" else False)
+                return traverse_whole(c, h, r, d, capacity,
+                                      use_spheres=use_spheres,
+                                      use_pallas=kernel, scene_of_query=soq,
+                                      owner_of_query=owner, payload=payload,
+                                      streamed=streamed, tiles=tiles)
+            if mode == "wavefront_fused":
+                return _traverse_fused(c, h, r, d, capacity, use_spheres,
+                                       use_pallas_traverse, owner=owner,
+                                       payload=payload, max_depth=max_depth)
+            return _traverse(c, h, r, d, capacity, use_spheres, owner=owner,
+                             payload=payload, max_depth=max_depth)
 
     if batch == "single":
         fn = base
@@ -578,19 +593,20 @@ def _sharded_traversal_fn(mode: str, capacity: int, use_spheres: bool,
 
     def local(nv, c, h, r, d):
         _TRACE_COUNTS[key] = _TRACE_COUNTS.get(key, 0) + 1
-        if mode == "wavefront_persistent":
-            assert max_depth is None, \
-                "the persistent arm has no depth cap (DESIGN.md §7)"
-            return traverse_whole(c, h, r, d, capacity,
-                                  use_spheres=use_spheres,
-                                  use_pallas=use_pallas_traverse,
-                                  streamed=streamed, num_valid=nv)
-        if mode == "wavefront_fused":
-            return _traverse_fused(c, h, r, d, capacity, use_spheres,
-                                   use_pallas_traverse, num_valid=nv,
-                                   max_depth=max_depth)
-        return _traverse(c, h, r, d, capacity, use_spheres, num_valid=nv,
-                         max_depth=max_depth)
+        with jax.named_scope("collide_traversal"):
+            if mode == "wavefront_persistent":
+                assert max_depth is None, \
+                    "the persistent arm has no depth cap (DESIGN.md §7)"
+                return traverse_whole(c, h, r, d, capacity,
+                                      use_spheres=use_spheres,
+                                      use_pallas=use_pallas_traverse,
+                                      streamed=streamed, num_valid=nv)
+            if mode == "wavefront_fused":
+                return _traverse_fused(c, h, r, d, capacity, use_spheres,
+                                       use_pallas_traverse, num_valid=nv,
+                                       max_depth=max_depth)
+            return _traverse(c, h, r, d, capacity, use_spheres, num_valid=nv,
+                             max_depth=max_depth)
 
     mesh = make_collision_mesh(shards)
     sm = jax.jit(shard_collision_traversal(local, mesh))
@@ -617,7 +633,8 @@ def traversal_cache_info() -> dict:
 def _stats_to_counters(st, mode: str, replays: int = 0,
                        extra_lanes: int = 0,
                        meta_format: str = "fp32") -> Counters:
-    st = jax.device_get(st)
+    with TraceAnnotation("executor.sync", what="counters"):
+        st = jax.device_get(st)
     c = Counters()
 
     def tot(x):
@@ -709,6 +726,25 @@ def _scene_tables(octrees: List[Octree], padded: bool, fmt: str = "fp32"):
         _TABLE_CACHE.pop(next(iter(_TABLE_CACHE)))
     _TABLE_CACHE[key] = ([weakref.ref(t) for t in octrees], tables)
     return tables
+
+
+def _stage(plan: QueryPlan) -> QueryPlan:
+    """The plan with its host arrays (OBB fields and any lanes) placed on
+    the default device by one ``jax.device_put``, under an
+    ``executor.stage`` span: the transfer jit's argument handling would
+    otherwise make inside the dispatch.  Device arrays pass through."""
+    lanes = ("obb_c", "obb_h", "obb_r", "scene_of_query", "owner_of_query",
+             "payload")
+    host = {f: getattr(plan, f) for f in lanes}
+    host = {f: a for f, a in host.items()
+            if a is not None and not isinstance(a, jax.Array)}
+    with TraceAnnotation("executor.stage", queries=plan.num_queries):
+        return dataclasses.replace(plan, **jax.device_put(host))
+
+
+def _fetch_verdict(verdict) -> np.ndarray:
+    with TraceAnnotation("executor.sync", what="verdict"):
+        return np.asarray(jax.device_get(verdict))
 
 
 class CollisionEngine:
@@ -927,41 +963,44 @@ class CollisionEngine:
         superset of the full-depth run — coarser, never missing a
         collision.
         """
-        t0 = time.perf_counter()
-        if plan.num_scenes != len(self.octrees):
-            raise ValueError(
-                f"plan carries {plan.num_scenes} scene(s) but the engine "
-                f"holds {len(self.octrees)}")
-        assert plan.num_scenes == 1 or self.cfg.device_resident, \
-            "multi-scene batching needs a device mode"
-        if plan.grouped and not self.cfg.device_resident:
-            raise ValueError(
-                "owner/payload plans need a device-resident mode; lower to "
-                "a boolean plan and reduce on the host instead")
-        if max_depth is not None:
-            if not self.supports_depth_cap:
+        with TraceAnnotation("engine.execute", queries=plan.num_queries,
+                             kind=plan.kind):
+            t0 = time.perf_counter()
+            if plan.num_scenes != len(self.octrees):
                 raise ValueError(
-                    f"max_depth needs a depth-cappable mode "
-                    f"({', '.join(DEPTH_CAP_MODES)}), not "
-                    f"{self.cfg.mode!r}")
-            if plan.grouped or plan.num_scenes > 1:
+                    f"plan carries {plan.num_scenes} scene(s) but the engine "
+                    f"holds {len(self.octrees)}")
+            assert plan.num_scenes == 1 or self.cfg.device_resident, \
+                "multi-scene batching needs a device mode"
+            if plan.grouped and not self.cfg.device_resident:
                 raise ValueError(
-                    "max_depth serves single-scene boolean plans (the "
-                    "degraded service path); grouped/multi-scene plans "
-                    "run at full depth")
-            if max_depth < 1:
-                raise ValueError(f"max_depth must be >= 1, got {max_depth}")
-        if self.cfg.shards is not None:
-            value, counters = self._exec_sharded(plan, max_depth)
-        elif self.cfg.mode == "naive":
-            value, counters = self._exec_naive(plan)
-        elif self.cfg.device_resident:
-            value, counters = self._exec_device(plan, max_depth)
-        else:
-            value, counters = self._exec_host(plan, max_depth)
-        counters.wall_time_s = time.perf_counter() - t0
-        counters.num_queries = plan.num_queries
-        return plan.unflatten(value), counters
+                    "owner/payload plans need a device-resident mode; lower "
+                    "to a boolean plan and reduce on the host instead")
+            if max_depth is not None:
+                if not self.supports_depth_cap:
+                    raise ValueError(
+                        f"max_depth needs a depth-cappable mode "
+                        f"({', '.join(DEPTH_CAP_MODES)}), not "
+                        f"{self.cfg.mode!r}")
+                if plan.grouped or plan.num_scenes > 1:
+                    raise ValueError(
+                        "max_depth serves single-scene boolean plans (the "
+                        "degraded service path); grouped/multi-scene plans "
+                        "run at full depth")
+                if max_depth < 1:
+                    raise ValueError(
+                        f"max_depth must be >= 1, got {max_depth}")
+            if self.cfg.shards is not None:
+                value, counters = self._exec_sharded(plan, max_depth)
+            elif self.cfg.mode == "naive":
+                value, counters = self._exec_naive(plan)
+            elif self.cfg.device_resident:
+                value, counters = self._exec_device(plan, max_depth)
+            else:
+                value, counters = self._exec_host(plan, max_depth)
+            counters.wall_time_s = time.perf_counter() - t0
+            counters.num_queries = plan.num_queries
+            return plan.unflatten(value), counters
 
     # ------------------------------------------------------------------
     def _run(self, capacity: int, batch: str = "single",
@@ -991,7 +1030,10 @@ class CollisionEngine:
                      max_depth: Optional[int] = None):
         cfg = self.cfg
         Q = plan.num_queries
-        owner, payload = plan.owner_of_query, plan.payload
+        owner = plan.owner_of_query
+        # Routing and tiling below read ``plan``'s own (host) lanes; the
+        # traversal reads the staged copies.
+        staged = _stage(plan)
         fmt = self.meta_format if cfg.mode in CSR_MODES else "fp32"
         # Metadata residency is picked here, per (mode, statics) cache
         # key, so paper-scale scenes run the persistent megakernel with
@@ -1033,12 +1075,12 @@ class CollisionEngine:
                 else np.asarray(plan.scene_of_query),
                 None if owner is None else np.asarray(owner))
             perm = np.maximum(tm.perm, 0)
-            run_args = (jnp.asarray(plan.obb_c)[perm],
-                        jnp.asarray(plan.obb_h)[perm],
-                        jnp.asarray(plan.obb_r)[perm])
-            owner_t = None if owner is None else jnp.asarray(owner)[perm]
-            payload_t = (None if payload is None
-                         else jnp.asarray(payload)[perm])
+            run_args = (staged.obb_c[perm], staged.obb_h[perm],
+                        staged.obb_r[perm])
+            owner_t = (None if owner is None
+                       else staged.owner_of_query[perm])
+            payload_t = (None if plan.payload is None
+                         else staged.payload[perm])
             tiles = jax.tree.map(jnp.asarray, tm.tiles)
         if plan.num_scenes > 1 and cfg.mode in CSR_MODES:
             # Ragged flat frontier: one pool of (scene, query, CSR node)
@@ -1060,8 +1102,9 @@ class CollisionEngine:
                 run_arm = lambda cap, arm: self._run(
                     cap, streamed=streamed, meta_format=fmt,
                     use_pallas_traverse=arm)(
-                        plan.obb_c, plan.obb_h, plan.obb_r, multi,
-                        plan.scene_of_query, owner, payload)
+                        staged.obb_c, staged.obb_h, staged.obb_r, multi,
+                        staged.scene_of_query, staged.owner_of_query,
+                        staged.payload)
             run, took_ref = self._guarded(run_arm, upt, memo_key, plan)
             verdict, st, cap, replays = _escalate(
                 run, Q, worst, cfg, start=self._cap_memo.get(memo_key))
@@ -1079,8 +1122,9 @@ class CollisionEngine:
             took_ref = lambda: False
             verdict, st, cap, replays = _escalate(
                 lambda cap: self._run(cap, "scenes")(
-                    plan.obb_c.reshape(S, M, 3), plan.obb_h.reshape(S, M, 3),
-                    plan.obb_r.reshape(S, M, 3, 3), dev),
+                    staged.obb_c.reshape(S, M, 3),
+                    staged.obb_h.reshape(S, M, 3),
+                    staged.obb_r.reshape(S, M, 3, 3), dev),
                 M, worst, cfg, start=self._cap_memo.get(memo_key))
         else:
             memo_key = ("single", Q, plan.grouped, max_depth,
@@ -1095,8 +1139,9 @@ class CollisionEngine:
                 run_arm = lambda cap, arm: self._run(
                     cap, streamed=streamed, meta_format=fmt,
                     use_pallas_traverse=arm, max_depth=max_depth)(
-                        plan.obb_c, plan.obb_h, plan.obb_r,
-                        self.device_tree, None, owner, payload)
+                        staged.obb_c, staged.obb_h, staged.obb_r,
+                        self.device_tree, None, staged.owner_of_query,
+                        staged.payload)
             run, took_ref = self._guarded(run_arm, upt, memo_key, plan)
             verdict, st, cap, replays = _escalate(
                 run, Q, self._capacity(Q), cfg,
@@ -1108,7 +1153,7 @@ class CollisionEngine:
                                       extra_lanes=lanes, meta_format=fmt)
         if cfg.persistent and (fallback_reason is not None or took_ref()):
             counters.ref_arm_fallbacks = 1
-        verdict = np.asarray(jax.device_get(verdict))
+        verdict = _fetch_verdict(verdict)
         if plan.grouped:
             # Grouped verdicts are computed in a Q-sized buffer (owner ids
             # are compact); only the first G cells are meaningful.
@@ -1170,6 +1215,7 @@ class CollisionEngine:
                     f"{cfg.vmem_budget / 2**20:.1f} MiB); serve it sharded "
                     "with mode='wavefront_fused', or single-device where "
                     "the table streams")
+        staged = _stage(plan)
         shards = self.active_shards
         reshards = 0
         lost_total = 0
@@ -1179,10 +1225,9 @@ class CollisionEngine:
                     self.device_fault_injector(shards)
                 q_shard = -(-Q // shards)
                 pad = q_shard * shards - Q
-                obb_c = jnp.pad(jnp.asarray(plan.obb_c), ((0, pad), (0, 0)))
-                obb_h = jnp.pad(jnp.asarray(plan.obb_h), ((0, pad), (0, 0)))
-                obb_r = jnp.pad(jnp.asarray(plan.obb_r),
-                                ((0, pad), (0, 0), (0, 0)))
+                obb_c = jnp.pad(staged.obb_c, ((0, pad), (0, 0)))
+                obb_h = jnp.pad(staged.obb_h, ((0, pad), (0, 0)))
+                obb_r = jnp.pad(staged.obb_r, ((0, pad), (0, 0), (0, 0)))
                 counts = jnp.clip(
                     Q - jnp.arange(shards, dtype=jnp.int32) * q_shard,
                     0, q_shard)
@@ -1229,7 +1274,7 @@ class CollisionEngine:
         counters.pad_queries = pad
         counters.reshards = reshards
         counters.shards_lost = lost_total
-        verdict = np.asarray(jax.device_get(verdict))[:Q]
+        verdict = _fetch_verdict(verdict)[:Q]
         return verdict, counters
 
     # ------------------------------------------------------------------

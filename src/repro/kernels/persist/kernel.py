@@ -505,4 +505,5 @@ def make_persist_call(num_tiles: int, bq: int, fcap: int, depth: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        name="persist_traverse",
     )

@@ -119,4 +119,5 @@ def make_traverse_call(capacity: int, bn: int, use_spheres: bool,
         out_shape=jax.ShapeDtypeStruct((capacity // LANES, LANES),
                                        jnp.int32),
         interpret=interpret,
+        name="traverse_step",
     )

@@ -64,6 +64,18 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+#: What the chip benchmark's trace reduction matches a kernel's device
+#: events by.
+TPU_CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+def _assert_named_kernel(text: str, name: str) -> None:
+    """The compiled program holds a Pallas custom call named ``name``."""
+    calls = [l for l in text.splitlines() if TPU_CUSTOM_CALL in l]
+    assert calls, "no tpu_custom_call in the compiled program"
+    assert any(f"%{name}" in l for l in calls), (name, calls[0][:200])
+
+
 def test_chooser_layout_at_paper_scale():
     """The cubby table (14 MiB of fp32 rows) fits the v5e-derived resident
     budget, so the chooser keeps it resident and uncompressed."""
@@ -96,7 +108,7 @@ def _persist_text(one_chip, layout, fmt, n_max, bq, fcap) -> str:
     ("streamed", "fp32"), ("streamed", "bf16"), ("streamed", "u8")])
 def test_persist_megakernel_compiles(one_chip, layout, fmt):
     text = _persist_text(one_chip, layout, fmt, N_MAX, BQ, CAPACITY)
-    assert "tpu_custom_call" in text
+    _assert_named_kernel(text, "persist_traverse")
 
 
 def test_persist_megakernel_compiles_at_vmem_budget_edge(one_chip):
@@ -109,7 +121,7 @@ def test_persist_megakernel_compiles_at_vmem_budget_edge(one_chip):
     assert tuple(choose_meta_layout(DEPTH, n_max)) == ("resident", "fp32")
     text = _persist_text(one_chip, "resident", "fp32", n_max, MAX_TILE_BQ,
                          MAX_TILE_FRONTIER)
-    assert "tpu_custom_call" in text
+    _assert_named_kernel(text, "persist_traverse")
 
 
 def test_traverse_step_compiles(one_chip):
@@ -119,7 +131,7 @@ def test_traverse_step_compiles(one_chip):
         call, _shape(one_chip, (2,)), _shape(one_chip, (4,), jnp.float32),
         _shape(one_chip, (15, rows, LANES), jnp.float32),
         _shape(one_chip, (2, rows, LANES)))
-    assert "tpu_custom_call" in text
+    _assert_named_kernel(text, "traverse_step")
 
 
 def test_stream_compaction_compiles(one_chip):
