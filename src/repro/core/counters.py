@@ -107,6 +107,11 @@ class Counters:
     escalations: int = 0                # overflow replays before a clean run
     meta_rows_streamed: int = 0         # HBM metadata rows DMA'd (streamed layout)
     meta_bytes_streamed: int = 0        # rows x the format's packed row width
+    meta_gathers: int = 0               # persistent megakernel's metadata
+    #                                     gather products (MXU passes): one
+    #                                     per (tile, chunk, window) holding a
+    #                                     lane at a level; depends on how the
+    #                                     pool is cut into tiles and windows
     pad_queries: int = 0                # dead pool slots added by sharding /
     #                                     batch coalescing (zero work each —
     #                                     the live-prefix num_valid lane masks
@@ -165,6 +170,7 @@ class Counters:
         self.escalations += other.escalations
         self.meta_rows_streamed += other.meta_rows_streamed
         self.meta_bytes_streamed += other.meta_bytes_streamed
+        self.meta_gathers += other.meta_gathers
         self.pad_queries += other.pad_queries
         self.ref_arm_fallbacks += other.ref_arm_fallbacks
         self.rejected += other.rejected

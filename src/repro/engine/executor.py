@@ -655,6 +655,8 @@ def _stats_to_counters(st, mode: str, replays: int = 0,
     c.exit_histogram += hist.reshape(-1, hist.shape[-1]).sum(axis=0)
     if "meta_rows" in st:
         c.meta_rows_streamed = tot("meta_rows")
+    if "meta_gathers" in st:
+        c.meta_gathers = tot("meta_gathers")
     # Streamed rows are priced at the packed row format's width (the row
     # COUNT is format-independent — see counters.py).
     row_bytes = {"fp32": BYTES_META_STREAM, "bf16": BYTES_META_STREAM_BF16,
@@ -1180,8 +1182,9 @@ class CollisionEngine:
         re-pads and re-shards over the surviving device set and the
         launch replays there.  Because verdicts and counters are
         bitwise-identical across ANY shard count (the invariant above,
-        CI-enforced), the recovered run answers exactly like the healthy
-        mesh; only ``Counters.reshards`` / ``shards_lost`` (and the pad
+        CI-enforced; ``meta_gathers`` excepted, which counts the
+        megakernel's per-tile gathers), the recovered run answers exactly
+        like the healthy mesh; only ``Counters.reshards`` / ``shards_lost`` (and the pad
         count) betray that anything happened.  The reduced width is
         sticky on the engine (``active_shards``) until ``set_shards``
         re-probes the full device set; a loss with no survivors

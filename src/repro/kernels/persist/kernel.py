@@ -21,14 +21,16 @@ at a time; a drained frontier makes the remaining levels no-ops.
 
 Each level makes four passes over its live chunks:
 
-  1. **gather** — node-metadata rows are gathered window by window: the
-     rows of one window are transposed into columns and each lane picks
-     its row with a one-hot reduction (a TPU core has no vector gather
-     from a large table);
+  1. **gather** — node-metadata rows are gathered window by window (a
+     TPU core has no vector gather from a large table) by a one-hot
+     matmul on the MXU over byte planes: the window's words, lane-dense
+     by sheet, against a one-hot of each lane's row within its sheet;
+     each lane then takes its own sheet's bytes;
   2. **test** — decode the words in-register
      (:func:`repro.kernels.persist.ref.decode_meta_words`, shared with the
-     ref arm), gather the lanes' query OBBs from the tile's ``bq``-row OBB
-     block with a one-hot reduction, run the two-phase staged SACT via the
+     ref arm), gather the lanes' query OBBs, payloads and owners from the
+     tile's ``bq`` slots by one one-hot matmul on the MXU over byte
+     planes, run the two-phase staged SACT via the
      shared :func:`repro.kernels.sact.kernel.sact_tile`, fold terminal hits
      into the tile's per-group ``best`` column and count the work;
   3. **scan** — a lane expands iff it overlaps a non-terminal node and its
@@ -38,7 +40,17 @@ Each level makes four passes over its live chunks:
   4. **expand** — each next-frontier chunk finds its parents by range test
      against the parents' [base, base + count) child ranges: child ``k`` of
      parent ``i`` lands at ``base[i] + k`` and is node ``child_start[i] +
-     k`` — the order of the ref arm's scatter, without a scatter.
+     k`` — the order of the ref arm's scatter, without a scatter; the
+     parent's query, start and code reach the child by a one-hot matmul
+     on the MXU over byte planes.
+
+The one-hot matmuls (:func:`onehot_gather`) are exact: every int32 word —
+an OBB field's f32 bits, bitcast — is split into its four bytes, which
+bf16 holds exactly (0..255); a one-hot column has at most a single 1, so
+each f32 accumulator adds one byte and zeros; the bytes are joined back
+bitwise, sign bit included.  Every bit pattern round-trips, negative zero,
+denormals and NaN payloads too.  Pass 1 counts its matmuls into the
+``gathers`` scalar (``Counters.meta_gathers``), which the ref arm models.
 
 Children past ``fcap`` are dropped and counted; the count lands in
 ``Counters.frontier_overflow`` and the engine's escalate-on-overflow
@@ -100,15 +112,10 @@ from repro.core.counters import NUM_EXIT_CODES
 from repro.core.octree import META_ROW_ALIGN
 from repro.core.quantize import META_FORMAT_WORDS
 from repro.core.sact import PAYLOAD_INF, axis_tests_from_exit
-from repro.kernels.persist.ref import decode_meta_words
+from repro.kernels.persist.ref import CHUNK, RESIDENT_WINDOW, decode_meta_words
 # _EPS shared with every SACT arm: the bitwise identity across engines
 # depends on all of them using the same epsilon and op order.
 from repro.kernels.sact.kernel import _EPS, NUM_AXES, sact_tile
-
-#: Frontier lanes processed per pass.
-CHUNK = 256
-#: Rows per gather window of the resident layout (8 sheets of 128 rows).
-RESIDENT_WINDOW = 8 * META_ROW_ALIGN
 
 #: Per-tile stats column: per-level valid counts, exit histogram, scalars.
 STATS_ROWS = 48
@@ -117,19 +124,64 @@ _HIST_ROWS = _SCAL0 - _HIST0
 assert NUM_EXIT_CODES <= _HIST_ROWS
 #: Scalars, in order, at rows ``_SCAL0 ..`` of the stats column.
 STAT_SCALARS = ("nodes", "leaf", "axis_exec", "axis_dec", "sphere",
-                "overflow", "meta_rows")
+                "overflow", "meta_rows", "gathers")
+assert _SCAL0 + len(STAT_SCALARS) <= STATS_ROWS
 
 # SMEM counter slots (per-level counts occupy [0, 16)).
-_LEAF, _AXIS, _OVF, _ROWS = 16, 17, 18, 19
+_LEAF, _AXIS, _OVF, _ROWS, _GATH = 16, 17, 18, 19, 20
 # Stash scratch rows.  Rows 8..15 form one aligned group that the expand
-# pass transposes into parent columns.
+# pass reads as one (8, CHUNK) block of parent lanes.
 _W0, _PAY, _OWN, _MASK = 0, 4, 5, 6
 _BASE, _NCH, _START, _CODE, _Q = 8, 9, 10, 11, 12
+#: Rows of the per-tile query operand: 15 OBB words, payload, owner, pad.
+_QROWS = 24
+
+
+def byte_planes(vals: jax.Array) -> jax.Array:
+    """(R, K) int32 -> (4R, K) bf16 left operand of :func:`onehot_gather`:
+    row ``b * R + r`` holds byte ``b`` of ``vals[r]`` (0..255, exact in
+    bf16)."""
+    planes = [(vals >> (8 * b)) & 0xFF for b in range(4)]
+    return jnp.concatenate(planes, axis=0).astype(jnp.float32).astype(
+        jnp.bfloat16)
+
+
+def onehot_gather(planes: jax.Array, onehot: jax.Array, *, nseg: int = 1,
+                  sheet: jax.Array | None = None,
+                  rhs_t: bool = False) -> jax.Array:
+    """Exact per-lane picks of int32 values under a one-hot, on the MXU.
+
+    ``planes`` is :func:`byte_planes` of ``(R * nseg, K)`` values — with
+    ``nseg`` > 1, ``R`` values of ``nseg`` segments each, segment-minor.
+    ``onehot`` is ``(K, C)`` bool (``(C, K)`` with ``rhs_t``): lane ``c``
+    picks row ``k`` where it is set, nothing where its column is all
+    false.  One bf16 matmul with an f32 accumulator picks every byte at
+    once: each output is a byte times 1 plus zeros, exact.  With ``nseg``
+    > 1, lane ``c`` then takes segment ``sheet[0, c]`` of each value (none
+    if out of range).  The bytes are joined bitwise, so every int32 —
+    every f32 bit pattern, bitcast — comes back as it went in.  Returns
+    ``(R, C)`` int32; columns that picked nothing read 0.
+    """
+    hot = jnp.where(onehot, 1.0, 0.0).astype(jnp.bfloat16)
+    dims = (((1,), (1,)), ((), ())) if rhs_t else (((1,), (0,)), ((), ()))
+    prod = jax.lax.dot_general(planes, hot, dims,
+                               preferred_element_type=jnp.float32)
+    n = prod.shape[0] // (4 * nseg)
+    if nseg > 1:
+        hit = jax.lax.broadcasted_iota(jnp.int32, (nseg, prod.shape[1]),
+                                       0) == sheet
+        prod = jnp.concatenate(
+            [jnp.sum(jnp.where(hit, prod[i * nseg:(i + 1) * nseg], 0.0),
+                     axis=0, keepdims=True) for i in range(4 * n)], axis=0)
+    b = prod.astype(jnp.int32)
+    return (b[:n] | (b[n:2 * n] << 8) | (b[2 * n:3 * n] << 16)
+            | (b[3 * n:] << 24))
 
 
 def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
                    lane_ref, meta_ref, best_ref, stats_ref, fr_scr, st_scr,
-                   best_scr, hist_scr, cnt_smem, cb_smem, *win_scratch,
+                   qp_scr, best_scr, hist_scr, cnt_smem, cb_smem,
+                   *win_scratch,
                    bq: int, fcap: int, depth: int, n_rows: int,
                    use_spheres: bool, stream: bool, meta_fmt: str,
                    wsub: int):
@@ -150,10 +202,6 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
     def put(ref, row, c, val):
         ref[pl.ds(row, 1), pl.ds(pl.multiple_of(c * C, C), C)] = val
 
-    def lane_gather(onehot, col, zero):
-        """Per-lane pick of a (bq, 1) column under a (bq, C) one-hot."""
-        return jnp.sum(jnp.where(onehot, col, zero), axis=0, keepdims=True)
-
     # ---- per-tile init + seed frontier (slot 0) ------------------------
     # Live-prefix mask: live slots form each tile's prefix (the tile map
     # pads at tile tails) AND sit before the SMEM valid count (the sharded
@@ -163,8 +211,13 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
                       jnp.clip(nvalid_ref[0] - q_base, 0, bq))
     best_scr[...] = jnp.full((bq, 1), inf, jnp.int32)
     hist_scr[...] = jnp.zeros((_HIST_ROWS, 1), jnp.int32)
-    for i in range(_ROWS + 1):
+    for i in range(_GATH + 1):
         cnt_smem[i] = jnp.int32(0)
+    # Pass 2's left operand, once per tile: the slots' words lane-dense —
+    # rows 0..14 the OBB's f32 bits, 15 payload, 16 owner.
+    qp_scr[...] = byte_planes(jnp.concatenate(
+        [jax.lax.bitcast_convert_type(obb_ref[...], jnp.int32).T,
+         lane_ref[...].T, jnp.zeros((_QROWS - 17, bq), jnp.int32)], axis=0))
     for c in range(-(-bq // C)):
         lane = c * C + iota_c
         seed = lane < n_q
@@ -239,7 +292,7 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
             def _():
                 window_dma("start", w_first, 0)
 
-        iota_seg = jax.lax.broadcasted_iota(jnp.int32, (META_ROW_ALIGN, C), 0)
+        iota_row = jax.lax.broadcasted_iota(jnp.int32, (META_ROW_ALIGN, C), 0)
 
         def win_body(w, k):
             has_w = jnp.sum(jnp.where(iota_w == w, occ, 0)) > 0
@@ -258,13 +311,15 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
                     cnt_smem[_ROWS] = cnt_smem[_ROWS] + rows
                     sheet_lo = ((off_l + w * wsub) // META_ROW_ALIGN
                                 * META_ROW_ALIGN)
-                    cols = [meta_scr[ks, kk].T for kk in range(vpf)]
+                    sheets = meta_scr[ks]
                 else:
                     sheet_lo = w * RESIDENT_WINDOW
-                    cols = [meta_ref[level, kk,
-                                     pl.ds(pl.multiple_of(w * nseg, 8), nseg),
-                                     :].T
-                            for kk in range(vpf)]
+                    sheets = meta_ref[level, :,
+                                      pl.ds(pl.multiple_of(w * nseg, 8), nseg),
+                                      :]
+                # The window's words by sheet, lane-dense: (vpf * gseg, 128).
+                gseg = sheets.shape[1]
+                planes = byte_planes(sheets.reshape(vpf * gseg, META_ROW_ALIGN))
 
                 def chunk_body(c, carry):
                     valid = c * C + iota_c < n_live
@@ -273,17 +328,16 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
 
                     @pl.when(jnp.sum(jnp.where(in_w, 1, 0)) > 0)
                     def _():
+                        # Row within the sheet on the MXU, then the sheet.
                         local = idx - sheet_lo
-                        acc = [jnp.zeros((1, C), jnp.int32)] * vpf
-                        for g in range(nseg):
-                            onehot = (local - g * META_ROW_ALIGN) == iota_seg
-                            for kk in range(vpf):
-                                acc[kk] = acc[kk] + lane_gather(
-                                    onehot, cols[kk][:, g:g + 1], 0)
+                        words = onehot_gather(
+                            planes, (local & (META_ROW_ALIGN - 1)) == iota_row,
+                            nseg=gseg, sheet=local >> 7)
                         for kk in range(vpf):
                             put(st_scr, _W0 + kk, c,
-                                jnp.where(in_w, acc[kk],
+                                jnp.where(in_w, words[kk:kk + 1],
                                           rows_at(st_scr, _W0 + kk, c)))
+                        cnt_smem[_GATH] = cnt_smem[_GATH] + 1
                     return carry
                 jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
             return k + jnp.where(has_w, 1, 0)
@@ -300,11 +354,10 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
             words = [rows_at(st_scr, _W0 + kk, c) for kk in range(vpf)]
             xyz, full_l, child_start, child_mask, code_own = \
                 decode_meta_words(words, meta_fmt, level, pcode)
-            q_hot = (q - q_base) == iota_q                       # (bq, C)
-            f = [lane_gather(q_hot, obb_ref[:, i:i + 1], 0.0)
+            qw = onehot_gather(qp_scr[...], (q - q_base) == iota_q)
+            f = [jax.lax.bitcast_convert_type(qw[i:i + 1], jnp.float32)
                  for i in range(15)]
-            pay = lane_gather(q_hot, lane_ref[:, 0:1], 0)
-            own = lane_gather(q_hot, lane_ref[:, 1:2], 0)
+            pay, own = qw[15:16], qw[16:17]
             node_c = [lo[i] + (xyz[i].astype(jnp.float32) + 0.5) * cell
                       for i in range(3)]
             tt = [f[i] - node_c[i] for i in range(3)]
@@ -364,24 +417,29 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
         cnt_smem[_OVF] = cnt_smem[_OVF] + jnp.maximum(n_new - fcap, 0)
 
         # ---- pass 4: place children into the other frontier slot ------
+        iota_cc = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+
         def out_body(o, carry):
             p = o * C + iota_c
+            p_col = o * C + iota_cc                 # child position, sublanes
 
             def parent_body(c, acc):
                 hit = (cb_smem[c] < (o + 1) * C) & (cb_smem[c + 1] > o * C)
 
                 def add(acc):
                     par = st_scr[pl.ds(_BASE, 8),
-                                 pl.ds(pl.multiple_of(c * C, C), C)].T
-                    b = par[:, 0:1]
-                    owns = (b <= p) & (p < b + par[:, 1:2])          # (C, C)
-                    return (acc[0] + lane_gather(owns, par[:, 4:5], 0),
-                            acc[1] + lane_gather(owns, par[:, 2:3] - b, 0),
-                            acc[2] + lane_gather(owns, par[:, 3:4], 0))
+                                 pl.ds(pl.multiple_of(c * C, C), C)]
+                    b = par[0:1]
+                    owns_t = (b <= p_col) & (p_col < b + par[1:2])  # (C, C)
+                    vals = jnp.concatenate(
+                        [par[4:5], par[2:3] - b, par[3:4],
+                         jnp.zeros((5, C), jnp.int32)], axis=0)
+                    return acc + onehot_gather(byte_planes(vals), owns_t,
+                                               rhs_t=True)
                 return jax.lax.cond(hit, add, lambda a: a, acc)
-            z = jnp.zeros((1, C), jnp.int32)
-            q_n, rel_n, code_n = jax.lax.fori_loop(0, n_chunks, parent_body,
-                                                   (z, z, z))
+            got = jax.lax.fori_loop(0, n_chunks, parent_body,
+                                    jnp.zeros((8, C), jnp.int32))
+            q_n, rel_n, code_n = got[0:1], got[1:2], got[2:3]
             live = p < n_next
             put(fr_scr, 3 * nxt, o, jnp.where(live, q_n, 0))
             put(fr_scr, 3 * nxt + 1, o, jnp.where(live, rel_n + p, 0))
@@ -405,7 +463,7 @@ def persist_kernel(scal_ref, off_ref, cnt_ref, sot_ref, nvalid_ref, obb_ref,
     stats_ref[_HIST0:_SCAL0, :] = hist_scr[...]
     vals = (nodes, cnt_smem[_LEAF], cnt_smem[_AXIS], nodes * NUM_AXES,
             2 * nodes if use_spheres else jnp.int32(0), cnt_smem[_OVF],
-            cnt_smem[_ROWS])
+            cnt_smem[_ROWS], cnt_smem[_GATH])
     iota8 = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
     scal = jnp.zeros((8, 1), jnp.int32)
     for i, v in enumerate(vals):
@@ -418,7 +476,8 @@ def vmem_scratch_bytes(bq: int, fcap: int, vpf: int, stream: bool,
     """VMEM bytes of the kernel's scratch (frontier, stash, accumulators,
     streamed window pair), excluding the resident table and temporaries."""
     fpad = _frontier_lanes(bq, fcap)
-    total = (8 + 16) * fpad * 4 + bq * 128 * 4 + _HIST_ROWS * 128 * 4
+    total = ((8 + 16) * fpad * 4 + 4 * _QROWS * bq * 2 + bq * 128 * 4
+             + _HIST_ROWS * 128 * 4)
     if stream:
         total += 2 * vpf * _window_sheets(wsub) * 128 * 4
     return total
@@ -472,6 +531,7 @@ def make_persist_call(num_tiles: int, bq: int, fcap: int, depth: int,
     scratch = [
         pltpu.VMEM((8, fpad), jnp.int32),          # frontier, 2 slots x 3
         pltpu.VMEM((16, fpad), jnp.int32),         # per-lane stash
+        pltpu.VMEM((4 * _QROWS, bq), jnp.bfloat16),    # query byte planes
         pltpu.VMEM((bq, 1), jnp.int32),            # per-group best
         pltpu.VMEM((_HIST_ROWS, 1), jnp.int32),    # exit histogram
         pltpu.SMEM((32,), jnp.int32),              # scalar counters

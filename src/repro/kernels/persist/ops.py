@@ -110,14 +110,14 @@ MAX_TILE_FRONTIER = 65536
 #: internal scratch.
 KERNEL_VMEM_CAP = 100 * 1024 * 1024
 
-#: Compiler temporaries of the megakernel body (one-hot planes of the
-#: gathers and the (CHUNK, CHUNK) scan/placement planes) allowed on top of
-#: its declared scratch.  The v5e compile of the largest tile
-#: (MAX_TILE_BQ queries, MAX_TILE_FRONTIER lanes) over a depth-7 resident
-#: fp32 table of the whole DEFAULT_VMEM_BUDGET needs 6.5-6.75 MiB on top
-#: of table and scratch (it fails at a 6.5 MiB allowance, passes at
-#: 6.75 MiB; ``tests/test_tpu_compile.py`` compiles that corner); this
-#: allows more than twice that.
+#: Compiler temporaries of the megakernel body (one-hot operands and
+#: products of the MXU gathers and the (CHUNK, CHUNK) scan/placement
+#: planes) allowed on top of its declared scratch.  The v5e compile of the
+#: largest tile (MAX_TILE_BQ queries, MAX_TILE_FRONTIER lanes) over a
+#: depth-7 resident fp32 table of the whole DEFAULT_VMEM_BUDGET needs
+#: 1.25-1.5 MiB on top of table and scratch (it fails at a 1.25 MiB
+#: allowance, passes at 1.5 MiB; ``tests/test_tpu_compile.py`` compiles
+#: that corner); this allows ten times that.
 KERNEL_TEMP_BYTES = 16 * 1024 * 1024
 
 #: Default VMEM budget for the resident node-metadata table: what the
@@ -454,7 +454,7 @@ def _kernel_whole(obb_c, obb_h, obb_r, dev, capacity: int,
               axis_dec=sc["axis_dec"], sphere=sc["sphere"],
               overflow=sc["overflow"], per_level=per,
               exit_hist=tot[_HIST0:_HIST0 + NUM_EXIT_CODES],
-              meta_rows=sc["meta_rows"])
+              meta_rows=sc["meta_rows"], meta_gathers=sc["gathers"])
     return best.reshape(-1), st
 
 
@@ -593,7 +593,7 @@ def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
                 dev.scene_lo, dev.depth, capacity, use_spheres,
                 scene_of_query=soq_slot, w_min=w_min,
                 owner_of_query=owner_of_query, payload=payload,
-                stream_bq=bq_t if streamed else None,
+                tile_bq=bq_t,
                 stream_wsub=sub_window_rows(n_max) if streamed else None,
                 scene_off=off if streamed else None,
                 scene_counts=cnt if streamed else None,
@@ -631,7 +631,7 @@ def traverse_whole(obb_c, obb_h, obb_r, dev, capacity: int, *,
                               capacity, use_spheres,
                               scene_of_query=None, w_min=w_min,
                               owner_of_query=None, payload=payload,
-                              stream_bq=bq if streamed else None,
+                              tile_bq=bq,
                               stream_wsub=(sub_window_rows(n_max)
                                            if streamed else None),
                               scene_off=off if streamed else None,

@@ -53,6 +53,13 @@ other counters.  The fetched span of a touched window is its occupied
 extent clipped to the window and rounded OUT to whole
 :data:`repro.core.octree.META_ROW_ALIGN`-row DMA chunks (``floor128(lo) ..
 ceil128(hi)``), the kernel's exact descriptor arithmetic.
+
+**Gather model.**  With ``tile_bq`` given, the ``meta_gathers`` stat
+counts the kernel's metadata gather products: one per (tile, chunk,
+window) that holds a valid lane at a level, where a lane's chunk is its
+position in its tile's frontier over :data:`CHUNK` and its window that
+of the layout (:data:`RESIDENT_WINDOW` rows resident, ``stream_wsub``
+rows from the tile scene's sub-extent streamed).
 """
 from __future__ import annotations
 
@@ -68,6 +75,12 @@ from repro.core.octree import (MAX_DEPTH, META_ROW_ALIGN, _jnp_compact1by2,
 from repro.core.quantize import (BF16_START_BITS, GRID_BITS, META_FORMATS,
                                  U8_START_BITS)
 from repro.core.sact import NUM_AXES, PAYLOAD_INF, payload_min_update
+
+#: The megakernel's schedule, shared with kernel.py so the models here
+#: cannot drift from it: frontier lanes per pass and rows per gather window
+#: of the resident layout (8 sheets of 128 rows).
+CHUNK = 256
+RESIDENT_WINDOW = 8 * META_ROW_ALIGN
 
 
 def frontier_widths(capacity: int, w_min: int = 128) -> Tuple[int, ...]:
@@ -150,14 +163,32 @@ def _empty_stats():
         axis_dec=jnp.int32(0), sphere=jnp.int32(0), overflow=jnp.int32(0),
         per_level=jnp.zeros((MAX_DEPTH + 1,), jnp.int32),
         exit_hist=jnp.zeros((NUM_EXIT_CODES,), jnp.int32),
-        meta_rows=jnp.int32(0))
+        meta_rows=jnp.int32(0), meta_gathers=jnp.int32(0))
+
+
+def gather_pairs(tile: jax.Array, win: jax.Array,
+                 valid: jax.Array) -> jax.Array:
+    """Distinct (tile, chunk, window) keys among the valid lanes of one
+    level: the megakernel's gather products there.  Valid lanes form the
+    prefix and are sorted by tile, so a lane's chunk is its position past
+    its tile's first lane over :data:`CHUNK`."""
+    w = tile.shape[0]
+    i = jnp.arange(w, dtype=jnp.int32)
+    first = valid & ((i == 0) | (tile != jnp.roll(tile, 1)))
+    start = jax.lax.cummax(jnp.where(first, i, 0))
+    # A monotone id per (tile, chunk); invalid lanes sort last.
+    tc = jnp.where(valid, jnp.cumsum(first | ((i - start) % CHUNK == 0)),
+                   w + 1)
+    tc, win = jax.lax.sort((tc, jnp.where(valid, win, 0)), num_keys=2)
+    new = (i == 0) | (tc != jnp.roll(tc, 1)) | (win != jnp.roll(win, 1))
+    return jnp.sum(jnp.where(new & (tc <= w), 1, 0))
 
 
 def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                        depth: int, capacity: int, use_spheres: bool,
                        scene_of_query: Optional[jax.Array] = None,
                        w_min: int = 128, owner_of_query=None, payload=None,
-                       stream_bq: Optional[int] = None,
+                       tile_bq: Optional[int] = None,
                        stream_wsub: Optional[int] = None,
                        scene_off: Optional[jax.Array] = None,
                        scene_counts: Optional[jax.Array] = None,
@@ -189,10 +220,13 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
         and a pair expands only while its payload could still beat its
         group's best — boolean early exit is the identity-owner,
         zero-payload special case.
-      stream_bq / stream_wsub / scene_off / scene_counts / scene_of_tile:
-        model the megakernel's streamed metadata layout (see module
-        docstring): ``stream_bq`` is the kernel's query-tile width,
-        ``stream_wsub`` the fixed sub-level window size in rows,
+      tile_bq: the kernel's query-tile width; given, the ``meta_gathers``
+        stat models the kernel's gather products (module docstring),
+        else it stays 0.
+      stream_wsub / scene_off / scene_counts / scene_of_tile (with
+        ``tile_bq``): model the megakernel's streamed metadata layout (see
+        module docstring): ``stream_wsub`` is the fixed sub-level window
+        size in rows,
         ``scene_off`` / ``scene_counts`` the (S, depth+1) per-scene flat
         sub-extents of the level rows (S = 1 and offset 0 for a single
         scene), and ``scene_of_tile`` the (num_tiles,) scene id of each
@@ -225,9 +259,9 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
     model_stream = stream_wsub is not None
     if model_stream:
         assert scene_off is not None and scene_counts is not None \
-            and scene_of_tile is not None and stream_bq is not None, \
+            and scene_of_tile is not None and tile_bq is not None, \
             "streamed-window model needs the full (bq, wsub, extents) spec"
-        num_tiles = -(-Q // stream_bq)
+        num_tiles = -(-Q // tile_bq)
         num_wins = -(-n_max // stream_wsub)   # static window grid per level
     else:
         num_tiles = num_wins = 0
@@ -318,11 +352,11 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                 cnt_l = jax.lax.dynamic_index_in_dim(
                     scene_counts, level, axis=1, keepdims=False)    # (S,)
                 off_lane = off_l[sid] if ragged else off_l[0]
-                win = jnp.clip((idx - off_lane) // stream_wsub,
-                               0, num_wins - 1)
+                win_g = (idx - off_lane) // stream_wsub
+                win = jnp.clip(win_g, 0, num_wins - 1)
                 live = jnp.zeros((num_tiles, num_wins), jnp.int32).at[
-                    q // stream_bq, win].max(valid.astype(jnp.int32),
-                                             mode="drop")
+                    q // tile_bq, win].max(valid.astype(jnp.int32),
+                                           mode="drop")
                 off_t = off_l[scene_of_tile][:, None]       # (T, 1)
                 cnt_t = cnt_l[scene_of_tile][:, None]
                 wlo = jnp.arange(num_wins, dtype=jnp.int32)[None, :] \
@@ -336,6 +370,10 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                 meta_rows = st["meta_rows"] + jnp.sum(live * span)
             else:
                 meta_rows = st["meta_rows"]
+                win_g = idx // RESIDENT_WINDOW
+            gathers = st["meta_gathers"]
+            if tile_bq is not None:
+                gathers = gathers + gather_pairs(q // tile_bq, win_g, valid)
 
             st = dict(
                 nodes=st["nodes"] + n_valid,
@@ -346,7 +384,7 @@ def traverse_whole_ref(obb_c, obb_h, obb_r, node_meta, cell_sizes, scene_lo,
                 overflow=st["overflow"] + jnp.maximum(n_new - capacity, 0),
                 per_level=st["per_level"].at[level].set(n_valid),
                 exit_hist=st["exit_hist"].at[res.exit_code].add(term_valid),
-                meta_rows=meta_rows)
+                meta_rows=meta_rows, meta_gathers=gathers)
             return (level + 1, jnp.minimum(n_new, capacity), q_next,
                     idx_next, verdict, st)
         return branch

@@ -201,7 +201,7 @@ def test_persist_kernel_payload_lane_interpret_matches_ref():
     payload = jnp.asarray(rs.randint(0, 9, obbs.n).astype(np.int32))
     cap = 256
     ref = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
-                         use_spheres=False, use_pallas=False,
+                         use_spheres=False, use_pallas=False, bq=16,
                          payload=payload)
     pal = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
                          use_spheres=False, use_pallas=True,

@@ -10,10 +10,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core.geometry import OBBs, random_obbs
 from repro.core.octree import (align_rows, build_octree,
                                concat_device_octrees, device_octree)
+from repro.core.quantize import META_FORMATS
+from repro.core.sact import PAYLOAD_INF
 from repro.core.wavefront import (MODES, CollisionEngine, EngineConfig,
                                   query_batched_scenes, traversal_cache_info)
 from repro.data.robotics import make_scene, scene_trajectories
@@ -21,6 +24,7 @@ from repro.kernels.persist.ops import (META_LAYOUTS, SUB_WINDOW_ROWS,
                                        choose_meta_layout, meta_stream_bytes,
                                        meta_table_bytes, sub_window_rows,
                                        traverse_whole)
+from repro.kernels.persist.kernel import byte_planes, onehot_gather
 from repro.kernels.persist.ref import frontier_widths
 
 WORK_FIELDS = ("nodes_traversed", "leaf_tests", "axis_tests_executed",
@@ -62,24 +66,88 @@ def test_persistent_engine_bitwise_equivalence_on_bench_scenes():
         assert c.bytes_moved < ref_c.bytes_moved
 
 
+@pytest.mark.parametrize("fmt", META_FORMATS)
 @pytest.mark.parametrize("use_spheres", [False, True])
-def test_persist_kernel_interpret_matches_ref(use_spheres):
+def test_persist_kernel_interpret_matches_ref(use_spheres, fmt):
     """Pallas megakernel (interpret=True, multiple query tiles) == jnp ref:
-    verdicts and every stats field, bitwise."""
+    verdicts and every stats field — the gather count included — bitwise,
+    in every row format."""
     rs = np.random.RandomState(7)
     pts = rs.uniform(-1, 1, (2500, 3)).astype(np.float32)
     tree = build_octree(pts, depth=3)
-    dev = device_octree(tree)
+    dev = device_octree(tree, meta_format=fmt)
     obbs = random_obbs(jax.random.PRNGKey(7), 21)     # 2 tiles at bq=16
     cap = 256
     ref = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
-                         use_spheres=use_spheres, use_pallas=False)
+                         use_spheres=use_spheres, use_pallas=False, bq=16)
     pal = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
                          use_spheres=use_spheres, use_pallas=True,
                          interpret=True, bq=16)
     assert bool(jnp.all(ref[0] == pal[0]))
     for k in ref[1]:
         assert bool(jnp.all(ref[1][k] == pal[1][k])), k
+    assert int(ref[1]["meta_gathers"]) > 0
+
+
+#: int32 words whose bytes take 0x00, 0x7F, 0x80 and 0xFF, INT32_MIN, -1
+#: and PAYLOAD_INF; f32 bit patterns of -0.0, the smallest denormal, +-inf
+#: and NaNs with payloads.
+_I32_WORDS = (0, 0x7F7F7F7F, -0x7F7F7F80, -1, -(1 << 31), PAYLOAD_INF,
+              0x00FF807F, -0x7F80FF01)
+_F32_BITS = (-(1 << 31), 1, 0x7F800000, -0x800000, 0x7FC12345, 0x7F800001,
+             -0x3EDCBA9, 0x3F800000)
+
+
+@pytest.mark.parametrize("layout", ["window", "flat", "transposed"])
+@pytest.mark.parametrize("dtype", ["int32", "float32"])
+def test_onehot_gather_is_bit_exact(dtype, layout):
+    """The megakernel's MXU gather (interpret=True) returns every picked
+    word bit for bit, as a numpy gather does: special int32 and f32 bit
+    patterns, window rows 0, 127, 128 and 1023 through the sheet pick, and
+    zero for lanes whose selector column is all false."""
+    R, C = 8, 256
+    K = 1024 if layout == "window" else 128
+    rs = np.random.RandomState(5)
+    bits = rs.randint(-(1 << 31), (1 << 31) - 1, (R, K), dtype=np.int64)
+    special = _I32_WORDS if dtype == "int32" else _F32_BITS
+    rows = (0, 127, 128, 1023) if layout == "window" else (0, 1, 127)
+    for j, row in enumerate(rows):
+        bits[:, row] = np.roll(special, j)[:R]
+    bits = bits.astype(np.int32)
+    # Lanes pick the special rows, random rows, and nothing (-1, K).
+    idx = rs.randint(0, K, C).astype(np.int32)
+    idx[:len(rows)] = rows
+    idx[len(rows):len(rows) + 2] = (-1, K)
+    vals = bits.view(np.float32) if dtype == "float32" else bits
+    sheets = vals.reshape(R, K // 128, 128)
+
+    def kernel(v_ref, i_ref, o_ref):
+        v = v_ref[...]
+        if dtype == "float32":
+            v = jax.lax.bitcast_convert_type(v, jnp.int32)
+        i = i_ref[...]
+        if layout == "window":
+            onehot = (i & 127) == jax.lax.broadcasted_iota(
+                jnp.int32, (128, C), 0)
+            got = onehot_gather(byte_planes(v.reshape(R * 8, 128)), onehot,
+                                nseg=8, sheet=i >> 7)
+        elif layout == "flat":
+            got = onehot_gather(byte_planes(v[:, 0]), i == jax.lax.
+                                broadcasted_iota(jnp.int32, (K, C), 0))
+        else:
+            got = onehot_gather(byte_planes(v[:, 0]), i.T == jax.lax.
+                                broadcasted_iota(jnp.int32, (C, K), 1),
+                                rhs_t=True)
+        if dtype == "float32":
+            got = jax.lax.bitcast_convert_type(got, jnp.float32)
+        o_ref[...] = got
+
+    out = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((R, C), vals.dtype),
+        interpret=True)(jnp.asarray(sheets), jnp.asarray(idx[None, :]))
+    picked = (idx >= 0) & (idx < K)
+    want = np.where(picked, bits[:, np.clip(idx, 0, K - 1)], 0)
+    assert (np.asarray(out).view(np.int32) == want).all()
 
 
 def test_persist_kernel_spill_ring_counts_overflow():
@@ -264,12 +332,15 @@ def _slab_scene(seed=3, n_pts=4000, depth=5):
     return build_octree(pts[np.abs(pts[:, 2]) < 0.3], depth=depth)
 
 
-def test_streamed_kernel_interpret_matches_ref_and_resident():
+@pytest.mark.parametrize("fmt", META_FORMATS)
+def test_streamed_kernel_interpret_matches_ref_and_resident(fmt):
     """Streamed metadata windows (interpret-mode DMA machinery, multiple
     query tiles) == streamed jnp ref on EVERY stats field including the
-    meta_rows window schedule; == the resident layout on everything but
-    meta_rows (the layout cannot change work, only traffic)."""
-    dev = device_octree(_slab_scene())
+    meta_rows window schedule and the gather count; the resident kernel
+    == the resident ref likewise, and == the streamed layout on everything
+    but its two schedule counters (the layout cannot change work, only
+    traffic and the windows gathered from)."""
+    dev = device_octree(_slab_scene(), meta_format=fmt)
     obbs = random_obbs(jax.random.PRNGKey(3), 37)     # 3 tiles at bq=16
     cap = 2048
     kw = dict(use_spheres=False, bq=16)
@@ -278,17 +349,21 @@ def test_streamed_kernel_interpret_matches_ref_and_resident():
     pal = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
                          use_pallas=True, interpret=True, streamed=True,
                          **kw)
+    res_ref = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
+                             use_pallas=False, streamed=False, **kw)
     res = traverse_whole(obbs.center, obbs.half, obbs.rot, dev, cap,
                          use_pallas=True, interpret=True, streamed=False,
                          **kw)
     assert int(ref[1]["meta_rows"]) > 0
+    assert int(ref[1]["meta_gathers"]) > 0
     assert bool(jnp.all(ref[0] == pal[0]))
     for k in ref[1]:
         assert bool(jnp.all(ref[1][k] == pal[1][k])), k
     assert int(res[1]["meta_rows"]) == 0
     assert bool(jnp.all(res[0] == pal[0]))
     for k in ref[1]:
-        if k != "meta_rows":
+        assert bool(jnp.all(res_ref[1][k] == res[1][k])), k
+        if k not in ("meta_rows", "meta_gathers"):
             assert bool(jnp.all(res[1][k] == pal[1][k])), k
 
 

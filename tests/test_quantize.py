@@ -230,7 +230,8 @@ def test_pallas_interpret_kernel_bitwise_across_formats():
     cap = 4096                       # no overflow: global == tile-local
     ref = traverse_whole(obbs.center, obbs.half, obbs.rot,
                          device_octree(tree), cap,
-                         use_spheres=False, use_pallas=False, streamed=False)
+                         use_spheres=False, use_pallas=False, streamed=False,
+                         bq=16)
     assert int(ref[1]["overflow"]) == 0
     for fmt in META_FORMATS:
         dev = device_octree(tree, meta_format=fmt)
@@ -240,7 +241,8 @@ def test_pallas_interpret_kernel_bitwise_across_formats():
                                  interpret=True, streamed=stream, bq=16)
             assert bool(jnp.all(ref[0] == pal[0])), (fmt, stream)
             for k in ref[1]:
-                if k != "meta_rows":
+                # The streamed layout moves only its schedule counters.
+                if not stream or k not in ("meta_rows", "meta_gathers"):
                     assert bool(jnp.all(ref[1][k] == pal[1][k])), \
                         (fmt, stream, k)
 
