@@ -17,6 +17,10 @@ came; it counts as failed and as missing its latency.
 ``PERF.md`` gives the readings the limits were set from: the program's
 sound runs read at most float32 rounding, a micrometre at these scene
 sizes; the bfloat16 control reads tenths of a millimetre and more.
+
+These are the collision loops' limits.  A loop of another kind keeps its
+own beside it; whatever the loop, a run is correct when every value its
+check returns is within its limit (:func:`within`).
 """
 from __future__ import annotations
 
@@ -37,7 +41,12 @@ def readings(verdict: np.ndarray, sep: np.ndarray) -> Dict[str, float]:
             "false_hit_m": float(false.max(initial=0.0))}
 
 
+def within(checks: Dict[str, List[float]]) -> bool:
+    """Every value at or under its limit (a NaN is not)."""
+    return all(v <= lim for v, lim in checks.values())
+
+
 def judge(values: Dict[str, float]) -> Tuple[bool, Dict[str, List[float]]]:
     """(correct, {name: [value, limit]}) in :data:`LIMITS` order."""
     checks = {k: [values[k], lim] for k, lim in LIMITS.items()}
-    return all(v <= lim for v, lim in checks.values()), checks
+    return within(checks), checks

@@ -23,18 +23,21 @@ import time
 
 import numpy as np
 
-import run
-import traffic
+import run  # first: it puts the program's sources on the import path
+import collision  # noqa: E402
+import traffic  # noqa: E402
+
+OPEN = run.load_loop("open")
 
 
 def window_row(batcher, mix: dict, rate: float, seconds: float,
                rng: np.random.Generator) -> dict:
     mix = dict(mix, rate_per_s=rate)
-    offsets, reqs = run.open_requests(mix, seconds, rng)
+    offsets, reqs = OPEN.requests(mix, seconds, rng)
     launches0 = batcher.num_launches
     counter = run.compile_counter()
     counter.active, counter.count = True, 0
-    loop = run.open_loop(batcher, reqs, offsets, seconds)
+    loop = OPEN.open_loop(batcher, reqs, offsets, seconds)
     counter.active = False
     ok = loop.answered
     lat = loop.latency_s() * 1e3
@@ -80,8 +83,8 @@ def main(argv=None) -> int:
     rng_scene, rng_traffic, rng_warm = (np.random.default_rng(s)
                                         for s in seq.spawn(3))
     t0 = time.perf_counter()
-    engine, vox, _ = run.build(config, rng_scene)
-    batcher = run.warm_service(engine, vox, config, mix, rng_warm)
+    engine, vox, _ = collision.build(config, rng_scene)
+    batcher = OPEN.warm_service(engine, vox, config, mix, rng_warm)
     print(f"set-up {time.perf_counter() - t0:.1f} s", flush=True)
     rows = []
     try:

@@ -2,6 +2,7 @@
 the precision below the float32 the configurations state, in the
 program's place.  The same reference in float32 passes.  Run at a size a
 test holds; ``control.py`` runs it at the cells' own sizes."""
+import chipbench_paths  # noqa: F401  (first: the import path)
 import copy
 
 import numpy as np
@@ -30,6 +31,7 @@ def _small(cell_name):
 
 
 @pytest.mark.parametrize("cell", ["cubby_t3.traj_batch",
+                                  "dresser_t3.traj_batch",
                                   "dresser_t3.edge_serve"])
 @pytest.mark.parametrize("seed", SEEDS)
 def test_bfloat16_control_is_not_correct(cell, seed):

@@ -1,4 +1,5 @@
 """The benchmark's numpy FK against the program's jnp FK."""
+import chipbench_paths  # noqa: F401  (first: the import path)
 import jax.numpy as jnp
 import numpy as np
 

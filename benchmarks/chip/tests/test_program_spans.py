@@ -1,5 +1,6 @@
 """The program-span reduction, on hand-made events and on a trace of the
 engine recorded on the CPU (whose XLA operations stand in for a chip's)."""
+import chipbench_paths  # noqa: F401  (first: the import path)
 import jax
 import numpy as np
 import pytest

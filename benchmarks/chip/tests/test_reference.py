@@ -1,5 +1,6 @@
 """The plain reference: its SAT, its voxel grid, and its agreement with
 the program at a small size."""
+import chipbench_paths  # noqa: F401  (first: the import path)
 import numpy as np
 import pytest
 

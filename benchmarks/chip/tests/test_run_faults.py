@@ -2,6 +2,7 @@
 a chip: sound, it comes out correct; with the timed path broken underneath
 (half of each launch left unchecked, answers altered where the engine
 produces them) it comes out not correct."""
+import chipbench_paths  # noqa: F401  (first: the import path)
 import copy
 import time
 
@@ -12,7 +13,8 @@ import run
 import traffic
 from repro.engine import CollisionEngine
 
-CELLS = ("cubby_t3.traj_batch", "dresser_t3.edge_serve")
+CELLS = ("cubby_t3.traj_batch", "dresser_t3.traj_batch",
+         "dresser_t3.edge_serve")
 
 
 def _run(cell_name):

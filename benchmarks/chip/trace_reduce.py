@@ -6,6 +6,13 @@ Host spans are the benchmark's own ``jax.profiler.TraceAnnotation`` events
 (named ``bench.*``) on the host plane, which shares the device events'
 clock.  Everything is reduced inside the ``bench.window`` span.
 
+A kernel is known by its operation's name: the HLO instruction's name
+without its ``.N`` suffix, which for a Pallas call is the ``name=`` it
+was given (``%persist_traverse.1 = ... custom-call(...)`` is
+``persist_traverse``).  Every Pallas call (custom-call target
+``tpu_custom_call``) in the window gets its device time and event count
+under that name, with no list of kernels to keep.
+
 :func:`reduce_events` works on plain ``(name, text, start_ns, end_ns)``
 tuples, so ``tests/test_trace_reduce.py`` checks it on a trace recorded on
 the CPU, whose XLA operations appear as host events carrying ``hlo_op``.
@@ -24,6 +31,8 @@ WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
 #: Idle gaps attributed to host activity, longest first.
 MAX_GAPS = 4000
+#: What marks a device operation as a Pallas call on the TPU.
+PALLAS_CALL = 'custom_call_target="tpu_custom_call"'
 
 Event = Tuple[str, str, float, float]     # name, searchable text, start, end
 
@@ -47,6 +56,13 @@ def short_name(name: str) -> str:
     target = re.search(r'custom_call_target="([^"]+)"', rhs)
     return " ".join([lhs] + ([op.group(1)] if op else [])
                     + ([target.group(1)] if target else []))
+
+
+def op_name(name: str) -> str:
+    """The instruction's name of a device operation's event name, without
+    its ``%`` and ``.N`` suffix."""
+    head = (name.split() or [""])[0]
+    return re.sub(r"\.\d+$", "", head.lstrip("%"))
 
 
 def _text(ev) -> str:
@@ -148,11 +164,13 @@ def reduce_events(device: Sequence[Sequence[Event]],
                   kernels: Optional[Dict[str, str]] = None) -> dict:
     """Reduce one traced window.
 
-    ``device`` holds one event list per chip; ``kernels`` maps a kernel's
-    metric name to a substring of its operation's name or stats.  Returns
-    seconds: ``window_s``, ``busy_s`` (mean over chips), per-kernel
-    ``kernel_s`` and ``kernel_events``, ``spans`` ({name: [(start, end,
-    device busy inside)]}), ``device_ops`` and ``idle_gaps`` (top 10).
+    ``device`` holds one event list per chip; ``kernels`` maps a short
+    name a reader uses to the name of the operation it stands for
+    (:func:`op_name`).  Returns seconds: ``window_s``, ``busy_s`` (mean
+    over chips), ``kernel_s`` and ``kernel_events`` of every Pallas call
+    by its name and of every name in ``kernels``, ``spans`` ({name:
+    [(start, end, device busy inside)]}), ``device_ops`` and
+    ``idle_gaps`` (top 10).
     """
     spans: Dict[str, List[Tuple[float, float]]] = {}
     for evs in host:
@@ -168,18 +186,22 @@ def reduce_events(device: Sequence[Sequence[Event]],
               for evs in device]
     busy = sum(sum(e - s for s, e in m) for m in merged) / len(merged)
     ops: Dict[str, float] = {}
-    ktime = {k: 0.0 for k in (kernels or {})}
-    kcount = {k: 0 for k in (kernels or {})}
+    kernels = kernels or {}
+    ktime = {k: 0.0 for k in kernels}
+    kcount = {k: 0 for k in kernels}
     for evs in device:
         for name, text, s, e in evs:
             d = min(e, hi) - max(s, lo)
             if d <= 0:
                 continue
             ops[name] = ops.get(name, 0.0) + d
-            for k, pat in (kernels or {}).items():
-                if pat in text:
-                    ktime[k] += d
-                    kcount[k] += 1
+            op = op_name(name)
+            keys = [k for k, n in kernels.items() if n == op]
+            if PALLAS_CALL in text and op not in kernels:
+                keys.append(op)
+            for k in keys:
+                ktime[k] = ktime.get(k, 0.0) + d
+                kcount[k] = kcount.get(k, 0) + 1
     first = merged[0]
     span_busy = {n: [(s, e, covered(first, s, e)) for s, e in v]
                  for n, v in spans.items()}

@@ -5,12 +5,13 @@ A unit of work is ``unit_segments`` straight joint-space segments of
 waypoint), and sent as one flat query set.  A segment starts uniform in the
 joint limits and ends per ``goal`` (see :func:`fk.segments`).
 
-``loop`` is ``"closed"`` (one client sends a unit, waits for its verdicts,
-sends the next, cycling a pool of ``pool`` units) or ``"open"`` (units
-arrive on a schedule, ``rate_per_s`` of them a second, whatever the
-service does).  Open-loop arrivals are Poisson arrivals conditioned on
-their count: ``round(rate_per_s * seconds)`` times uniform over the window,
-so every seed offers the same load in another order.
+``loop`` names the module that drives the mix, ``loops/<loop>.py``:
+``closed`` (one client sends a unit, waits for its verdicts, sends the
+next, cycling a pool of ``pool`` units) or ``open`` (units arrive on a
+schedule, ``rate_per_s`` of them a second, whatever the service does).
+Open-loop arrivals are Poisson arrivals conditioned on their count:
+``round(rate_per_s * seconds)`` times uniform over the window, so every
+seed offers the same load in another order.
 
 With ``fixed_work_seed`` the units are drawn from that seed and the run's
 seed only orders them: every seed then sends the same work.  A pool of a
