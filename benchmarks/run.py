@@ -29,12 +29,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import (emit, time_call, time_group,
+from benchmarks.common import (emit, time_group,
                                work_model_cycles, work_model_energy_pj,
                                write_results)
 from repro.core.ballquery import ball_query_pray, ball_query_psphere
-from repro.core.fps import (farthest_point_sampling, random_sampling,
-                            sampling_spread)
 from repro.core.geometry import OBBs
 from repro.core.octree import build_octree
 from repro.core.quantize import META_FORMATS
@@ -317,64 +315,6 @@ def fig17_radius_sweep(S):
             base = dt
         emit(f"fig17/psphere_r{r}", dt * 1e6,
              f"rel={dt/base:.2f};nodes={c.nodes_traversed}")
-
-
-# ---------------------------------------------------------------------------
-# Fig. 9 — sampling strategy: FPS vs random in the PointNet++ front end
-# ---------------------------------------------------------------------------
-
-def fig9_sampling(S):
-    from repro.models.pointnet import init_pointnet, pointnet_encode
-    rs = np.random.RandomState(0)
-    cloud = jnp.asarray(rs.uniform(-1, 1, (2, 2048, 3)).astype(np.float32))
-    params = init_pointnet(jax.random.PRNGKey(0))
-    enc_fps = jax.jit(lambda p, c: pointnet_encode(p, c, "fps"))
-    enc_rnd = jax.jit(lambda p, c, k: pointnet_encode(p, c, "random", k))
-    key = jax.random.PRNGKey(1)
-    t_fps = time_call(lambda: enc_fps(params, cloud).block_until_ready())
-    t_rnd = time_call(
-        lambda: enc_rnd(params, cloud, key).block_until_ready())
-    pts = cloud[0]
-    s_fps = float(sampling_spread(pts, farthest_point_sampling(pts, 256)))
-    s_rnd = float(np.mean([float(sampling_spread(
-        pts, random_sampling(jax.random.PRNGKey(s), 2048, 256)))
-        for s in range(4)]))
-    emit("fig9/fps", t_fps * 1e6, f"spread={s_fps:.4f}")
-    emit("fig9/random", t_rnd * 1e6,
-         f"spread={s_rnd:.4f};latency_saving={1-t_rnd/t_fps:.2f};"
-         f"note=collision_gate_catches_quality_loss")
-
-
-# ---------------------------------------------------------------------------
-# Fig. 18 — full pipeline latency breakdown with collision gate
-# ---------------------------------------------------------------------------
-
-def fig18_pipeline(S):
-    from repro.core.pipeline import plan_with_collision_gate
-    from repro.models.planner import init_planner, rollout
-    sc, tree, _ = get_scene("tabletop", S["points"], S["depth"], 1, 2)
-    engine = CollisionEngine(tree, EngineConfig(mode="wavefront_fused"))
-    params = init_planner(jax.random.PRNGKey(0))
-    rs = np.random.RandomState(2)
-    cloud = jnp.asarray(
-        sc.points[rs.choice(len(sc.points), 2048, replace=False)])
-    q0 = jnp.asarray(rs.uniform(-1, 1, 7).astype(np.float32))
-    goal = jnp.asarray(rs.uniform(-1, 1, 7).astype(np.float32))
-    fns = {"rollout": jax.jit(rollout, static_argnames=("num_steps",
-                                                        "sampling"))}
-    for sampling in ("fps", "random"):
-        plan_with_collision_gate(params, fns, engine, cloud, q0, goal,
-                                 num_steps=20, sampling=sampling,
-                                 key=jax.random.PRNGKey(3))
-        res2 = plan_with_collision_gate(params, fns, engine, cloud, q0,
-                                        goal, num_steps=20,
-                                        sampling=sampling,
-                                        key=jax.random.PRNGKey(3))
-        t = res2.timings
-        emit(f"fig18/{sampling}", (t["plan_s"] + t["collision_s"]) * 1e6,
-             f"plan_us={t['plan_s']*1e6:.0f};"
-             f"collision_us={t['collision_s']*1e6:.0f};"
-             f"collision_free={res2.collision_free}")
 
 
 # ---------------------------------------------------------------------------
@@ -857,7 +797,6 @@ def roofline_table(S):
 
 
 BENCHES = {
-    "fig9": fig9_sampling,
     "fig11": fig11_collision_speedup,
     "fig12": fig12_unit_utilization,
     "fig13": fig13_latency_sensitivity,
@@ -866,7 +805,6 @@ BENCHES = {
     "fig16": fig16_energy,
     "table4": table4_pray_psphere,
     "fig17": fig17_radius_sweep,
-    "fig18": fig18_pipeline,
     "fig19": fig19_mcl,
     "batched": batched_throughput,
     "ragged": ragged_scenes,

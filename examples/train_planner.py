@@ -1,17 +1,21 @@
-"""End-to-end driver: train the MpiNet-lite neural planner and evaluate it
-WITH the explicit collision gate (the paper's full pipeline).
+"""End-to-end example: behaviour-clone the MPiNets policy (small preset) and
+plan WITH the explicit collision gate (the paper's full pipeline).
 
-    PYTHONPATH=src python examples/train_planner.py            # ~2 min CPU
-    PYTHONPATH=src python examples/train_planner.py --full     # ~100M params
+    PYTHONPATH=src python examples/train_planner.py            # ~1 min CPU
+    PYTHONPATH=src python examples/train_planner.py --steps 300
 
 Stages:
   1. Build a synthetic Cubby scene + octree (repro.data.robotics).
-  2. Generate expert trajectories (goal-seeking with collision-aware
-     rejection) and behaviour-clone the planner on (cloud, q, goal) -> dq.
-  3. Evaluate rollouts; every plan passes through the explicit collision
-     gate (core/pipeline.py) — the paper's safety argument in action.
+  2. Generate expert steps (goal-seeking with collision-aware rejection)
+     and behaviour-clone the policy on (cloud at q, q) -> normalised step.
+  3. Plan a batch of problems; every waypoint passes through the explicit
+     collision gate (core/pipeline.py) — the paper's safety argument.
+
+The published widths (``MPINETS``) train the same way; on the CPU they
+are slow, so this example uses the ``SMALL`` preset.
 """
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -21,19 +25,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.geometry import arm_link_obbs
 from repro.core.octree import build_octree
 from repro.core.pipeline import check_trajectory, plan_with_collision_gate
-from repro.core.wavefront import CollisionEngine, EngineConfig
-from repro.data.robotics import make_scene
-from repro.models.planner import init_planner, planner_loss, rollout
+from repro.data.robotics import PANDA_JOINT_HI, PANDA_JOINT_LO, make_scene
+from repro.engine import CollisionEngine, EngineConfig
+from repro.models.planner import (SMALL, bc_loss, init_mpinets, normalize,
+                                  target_points)
 from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
 
 
-def make_expert_data(engine, scene, n_episodes, steps, rs):
-    """Greedy goal-seeking expert with collision-aware step rejection."""
-    lo = np.asarray([-2.8, -1.7, -2.8, -3.0, -2.8, 0.0, -2.8], np.float32)
-    hi = np.asarray([2.8, 1.7, 2.8, -0.1, 2.8, 3.7, 2.8], np.float32)
+def make_expert_data(engine, n_episodes, steps, rs):
+    """Greedy goal-seeking expert with collision-aware step rejection:
+    (q, goal, step) in radians."""
+    lo, hi = PANDA_JOINT_LO, PANDA_JOINT_HI
     qs, goals, deltas = [], [], []
     for _ in range(n_episodes):
         q = rs.uniform(lo, hi).astype(np.float32)
@@ -45,87 +49,69 @@ def make_expert_data(engine, scene, n_episodes, steps, rs):
             if bool(np.asarray(flags)[0]):
                 # collision: deflect with a random detour step
                 step_v = rs.uniform(-0.3, 0.3, 7).astype(np.float32)
-                cand = q + step_v
+                cand = np.clip(q + step_v, lo, hi)
             qs.append(q.copy())
             goals.append(goal.copy())
-            deltas.append(step_v.astype(np.float32))
+            deltas.append((cand - q).astype(np.float32))
             q = cand
-    return (np.stack(qs), np.stack(goals), np.stack(deltas))
+    return np.stack(qs), np.stack(goals), np.stack(deltas)
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--full", action="store_true",
-                    help="~100M-param planner, more data/steps")
-    ap.add_argument("--steps", type=int, default=None)
-    ap.add_argument("--sampling", default="random",
-                    choices=["random", "fps"])
+    ap.add_argument("--steps", type=int, default=60)
     args = ap.parse_args()
-
-    widen = 10 if args.full else 1           # 10x MLP ≈ 100M params
-    n_eps = 24 if args.full else 6
-    train_steps = args.steps or (300 if args.full else 60)
-    cloud_pts = 1024
 
     rs = np.random.RandomState(0)
     print("building scene + octree ...")
     scene = make_scene("cubby", num_points=65536)
-    tree = build_octree(scene.points, depth=6)
-    engine = CollisionEngine(tree, EngineConfig(mode="wavefront_fused"))
+    engine = CollisionEngine(build_octree(scene.points, depth=6),
+                             EngineConfig(mode="wavefront_fused"))
 
     print("generating expert data ...")
-    qs, goals, deltas = make_expert_data(engine, scene, n_eps, 20, rs)
-    cloud = jnp.asarray(scene.points[
-        rs.choice(len(scene.points), cloud_pts, replace=False)])
+    qs, goals, deltas = make_expert_data(engine, 6, 20, rs)
     n = len(qs)
     print(f"  {n} expert tuples")
 
-    params = init_planner(jax.random.PRNGKey(0), widen=widen)
-    n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
-    print(f"planner params: {n_params/1e6:.1f}M")
-
-    opt_cfg = OptConfig(lr=3e-4, warmup_steps=10, total_steps=train_steps,
+    model = init_mpinets(SMALL, seed=0)
+    print(f"planner params: {model.num_params / 1e3:.0f}k")
+    span = PANDA_JOINT_HI - PANDA_JOINT_LO
+    opt_cfg = OptConfig(lr=3e-4, warmup_steps=10, total_steps=args.steps,
                         weight_decay=0.01)
-    opt_state = init_opt_state(params, opt_cfg)
-    B = 32
+    params, opt_state = model.params, init_opt_state(model.params, opt_cfg)
+    B = 16
     loss_grad = jax.jit(jax.value_and_grad(
-        lambda p, b, k: planner_loss(p, b, args.sampling, k)[0]))
+        lambda p, obs, tgt, q, d: bc_loss(model, p, obs, tgt, q, d)))
 
     t0 = time.time()
-    for step in range(train_steps):
+    for step in range(args.steps):
         idx = rs.randint(0, n, B)
-        batch = {"cloud": jnp.broadcast_to(cloud[None], (B,) + cloud.shape),
-                 "q": jnp.asarray(qs[idx]), "goal": jnp.asarray(goals[idx]),
-                 "expert_delta": jnp.asarray(deltas[idx])}
-        loss, grads = loss_grad(params, batch,
-                                jax.random.PRNGKey(1000 + step))
+        obs = scene.points[rs.choice(len(scene.points),
+                                     (B, SMALL.obstacle_points))]
+        tgt = target_points(model, jnp.asarray(goals[idx]))
+        loss, grads = loss_grad(params, jnp.asarray(obs), tgt,
+                                normalize(jnp.asarray(qs[idx])),
+                                jnp.asarray(2.0 * deltas[idx] / span))
         params, opt_state, _ = adamw_update(params, grads, opt_state,
                                             opt_cfg)
-        if step % max(train_steps // 10, 1) == 0:
+        if step % max(args.steps // 10, 1) == 0:
             print(f"step {step:4d}  bc-loss {float(loss):.4f}  "
                   f"({time.time()-t0:.0f}s)")
 
-    print("\nevaluating with the explicit collision gate ...")
-    fns = {"rollout": jax.jit(rollout,
-                              static_argnames=("num_steps", "sampling"))}
-    ok, caught = 0, 0
-    for ep in range(8):
-        q0 = jnp.asarray(rs.uniform(-1.5, 1.5, 7).astype(np.float32))
-        goal = jnp.asarray(rs.uniform(-1.5, 1.5, 7).astype(np.float32))
-        res = plan_with_collision_gate(params, fns, engine, cloud, q0, goal,
-                                       num_steps=20,
-                                       sampling=args.sampling,
-                                       key=jax.random.PRNGKey(ep))
-        reached = float(np.linalg.norm(res.trajectory[-1]
-                                       - np.asarray(goal))) < 0.5
-        ok += res.collision_free and reached
-        caught += not res.collision_free
-        print(f"  ep{ep}: reached={reached} "
-              f"collision_free={res.collision_free} "
-              f"plan={res.timings['plan_s']*1e3:.0f}ms "
-              f"gate={res.timings['collision_s']*1e3:.0f}ms")
-    print(f"\nsuccess(collision-free & reached)={ok}/8; "
-          f"unsafe plans caught by the gate={caught}/8")
+    print("\nplanning 8 problems with the explicit collision gate ...")
+    model = dataclasses.replace(model, params=params)
+    obs = scene.points[rs.choice(len(scene.points),
+                                 (8, SMALL.obstacle_points))]
+    start = rs.uniform(PANDA_JOINT_LO, PANDA_JOINT_HI, (8, 7))
+    goal = rs.uniform(PANDA_JOINT_LO, PANDA_JOINT_HI, (8, 7))
+    res = plan_with_collision_gate(model, engine, obs, start, goal)
+    reached = np.linalg.norm(res.trajectories[:, -1] - goal, axis=1) < 0.5
+    for b in range(8):
+        print(f"  problem {b}: reached={reached[b]} "
+              f"collision_free={res.collision_free[b]}")
+    print(f"\nsuccess(collision-free & reached)="
+          f"{int((reached & res.collision_free).sum())}/8; unsafe plans "
+          f"caught by the gate={int((~res.collision_free).sum())}/8")
 
 
 if __name__ == "__main__":

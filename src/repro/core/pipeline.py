@@ -3,8 +3,9 @@
 RoboGPU Fig. 18: point-cloud processing (sampling + grouping) -> neural
 planner rollout -> explicit collision check of the proposed trajectory.
 The paper's safety argument is that the collision gate must be part of the
-pipeline; with RoboCore-style acceleration it adds no wall-clock to the
-critical path.  Stage timings are returned for the benchmark.
+pipeline; with RoboCore-style acceleration it adds little to the critical
+path.  :func:`plan_with_collision_gate` runs the MPiNets policy
+(:mod:`repro.models.planner`) over a batch of problems, then the gate.
 
 Every front-end here lowers through :mod:`repro.engine.plan` and executes
 on :meth:`repro.engine.executor.CollisionEngine.execute` — host-loop and
@@ -16,24 +17,38 @@ graph edges (see :mod:`repro.core.sweep`).
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Dict, Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.core.geometry import NUM_LINKS
 from repro.core.sweep import sweep_edges
-from repro.core.wavefront import CollisionEngine
+from repro.engine import CollisionEngine
 from repro.engine.plan import plan_trajectory
+from repro.models.planner import MpiNets, rollout, unnormalize
+
+
+@dataclasses.dataclass
+class PlannerStats:
+    """What one planning call did (:func:`plan_with_collision_gate`)."""
+
+    problems: int
+    steps: int                  # rollout steps, each a whole forward pass
+    fps_picks: int              # dependent FPS picks, per problem
+    ball_fill: Tuple[float, ...]  # neighbours found / k, per set abstraction
+    gate_obbs: int              # link OBBs the gate checked
 
 
 @dataclasses.dataclass
 class PipelineResult:
-    trajectory: np.ndarray          # (T+1, 7) joint waypoints
-    collision_free: bool
-    colliding_waypoints: np.ndarray  # (T+1,) bool
-    timings: Dict[str, float]
+    trajectories: np.ndarray    # (B, T+1, 7) joint waypoints, radians
+    normalized: np.ndarray      # (B, T+1, 7) the policy's joints in [-1, 1]
+    colliding: np.ndarray       # (B, T+1) bool, per waypoint
+    collision_free: np.ndarray  # (B,) bool
+    stats: PlannerStats
     counters: Optional[object] = None
 
 
@@ -94,34 +109,41 @@ def check_edges(engine: CollisionEngine, q_from: jax.Array, q_to: jax.Array,
                            counters=counters)
 
 
-def plan_with_collision_gate(planner_params, planner_fns, engine:
-                             CollisionEngine, cloud: jax.Array,
-                             q0: jax.Array, goal: jax.Array,
-                             num_steps: int = 40, sampling: str = "random",
-                             key=None) -> PipelineResult:
-    """One planning episode: encode -> rollout -> explicit collision gate.
+_radians = jax.jit(unnormalize)
 
-    ``planner_fns`` = (encode_fn, rollout_fn) from models/planner.py
-    signatures; kept injectable so benchmarks can swap sampling modes.
-    Stage walls are honest: each stage blocks on its own device work
-    (``block_until_ready``), so the planner's async dispatch is charged to
-    ``plan_s`` and never bleeds into ``collision_s``.  ``counters`` come
-    from the collision gate only.
+
+def plan_with_collision_gate(model: MpiNets, engine: CollisionEngine,
+                             obstacles, q_start, q_goal) -> PipelineResult:
+    """Plan B problems with the MPiNets policy and gate every waypoint.
+
+    ``obstacles`` (B, obstacle_points, 3) are each problem's obstacle
+    points, ``q_start`` and ``q_goal`` (B, 7) its joints in radians.  One
+    jitted rollout (:func:`repro.models.planner.rollout`) re-encodes the
+    cloud at every step; its (B, steps + 1) waypoints then go through the
+    collision gate as one trajectory plan.  Spans ``pipeline.rollout`` and
+    ``pipeline.gate`` (the engine's own spans inside it) time the two;
+    ``counters`` come from the gate.
     """
-    rollout = planner_fns["rollout"]
-    t0 = time.perf_counter()
-    traj = rollout(planner_params, cloud[None], q0[None], goal[None],
-                   num_steps, sampling, key)
-    traj = jax.block_until_ready(traj)
-    t_plan = time.perf_counter() - t0
-    traj = jax.device_get(traj)[0]                  # (T+1, 7)
-
-    t0 = time.perf_counter()
-    flags, counters = check_trajectory(engine, jnp.asarray(traj))
-    t_collision = time.perf_counter() - t0
+    cfg = model.cfg
+    B = int(np.shape(q_start)[0])
+    with TraceAnnotation("pipeline.rollout", problems=B, steps=cfg.steps):
+        q_norm, fills = rollout(model, jnp.asarray(obstacles, jnp.float32),
+                                jnp.asarray(q_start, jnp.float32),
+                                jnp.asarray(q_goal, jnp.float32))
+        waypoints = _radians(q_norm)
+        normalized, traj, fills = jax.device_get((q_norm, waypoints, fills))
+    with TraceAnnotation("pipeline.gate", waypoints=traj.shape[1]):
+        flags, counters = check_trajectories(engine, waypoints)
     flags = np.asarray(flags)
-    return PipelineResult(
-        trajectory=traj, collision_free=not bool(flags.any()),
-        colliding_waypoints=flags,
-        timings={"plan_s": t_plan, "collision_s": t_collision},
-        counters=counters)
+    enc = cfg.encoder
+    per_sa = B * cfg.steps * np.array(
+        [enc.sa1.centres * enc.sa1.neighbours,
+         enc.sa2.centres * enc.sa2.neighbours])
+    stats = PlannerStats(
+        problems=B, steps=cfg.steps, fps_picks=cfg.fps_picks,
+        ball_fill=tuple(float(f) for f in np.asarray(fills) / per_sa),
+        gate_obbs=int(flags.size) * NUM_LINKS)
+    return PipelineResult(trajectories=traj, normalized=normalized,
+                          colliding=flags,
+                          collision_free=~flags.any(axis=1), stats=stats,
+                          counters=counters)

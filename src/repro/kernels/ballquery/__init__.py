@@ -1,1 +1,1 @@
-from repro.kernels.ballquery.ops import ball_query_tiled  # noqa: F401
+from repro.kernels.ballquery.ops import ball_query  # noqa: F401

@@ -1,15 +1,29 @@
-"""Tiled fixed-radius neighbor search with per-tile early stop (Pallas).
+"""Batched ball query (``ball_query``): the first ``k`` points by index
+within a radius of each centre, for every centre of B problems in one
+Pallas call.
 
-RoboGPU §IV: ball query on RoboCore wins because (a) the custom intersection
-program runs inside the accelerator instead of bouncing to shader cores, and
-(b) traversal stops once a query's neighbor group is full.  This kernel is
-the dense-tile analogue: the grid walks point blocks sequentially for each
-query block, neighbor lists accumulate in a VMEM-resident output block, and a
-tile whose queries are ALL full skips its distance stage entirely
-(`lax.cond` — the tile-granular conditional return).
+Semantics are those of ``pointnet2_ops``' ``ball_query``: a point is a
+neighbour when ``d2 < r*r`` (float32, ``d = centre - point``, ``d2 =
+(dx*dx + dy*dy) + dz*dz``); the first ``k`` neighbours by point index fill
+the slots in order, and slots past the last neighbour repeat the first
+one (all 0 when a ball is empty).  The kernel also returns how many slots
+hold a neighbour of their own (the ball's fill).
 
-Matches `ball_query_ref` exactly (first-k by ascending point index) because
-point blocks are visited in ascending order.
+One grid step holds one problem's points in VMEM as a ``(3, N)`` plane
+(points on lanes) and a block of ``R`` centres (one a sublane row), and
+works in two phases:
+
+1. *Hits as bits.*  Each 128-point chunk ``c`` of the cloud is tested
+   against all ``R`` centres at once, and its hits are set as bit
+   ``c % 31`` of word ``c // 31`` of the point's lane: after one pass,
+   lane ``l`` of a row holds in a few int32 words which chunks have a
+   neighbour at lane ``l``.
+2. *First hits, one slot a step.*  The next neighbour of a row in index
+   order is the lane whose lowest set bit is lowest (ties to the lower
+   lane), so slot ``j`` of every row is one lowest-bit extraction (``w &
+   -w``, its exponent read from its float32 form), one lane minimum and
+   one bit cleared.  The loop runs as many steps as the fullest row of
+   the block has slots to fill, never more than ``k``.
 """
 from __future__ import annotations
 
@@ -17,59 +31,96 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+#: Chunks whose hits one int32 word holds (its sign bit stays clear).
+BITS = 31
 
 
-def ballquery_kernel(q_ref, p_ref, cnt_ref, idx_ref, *, radius: float,
-                     k: int, bn: int):
-    j = pl.program_id(1)
-    bm = q_ref.shape[0]
+def ball_query_kernel(c_ref, p_ref, idx_ref, cnt_ref, fill_ref, *, n: int,
+                      k: int, r2: float):
+    """``c_ref`` (1, R, 3) centres, ``p_ref`` (1, 3, Np) points; out:
+    ``idx_ref`` (1, R, k) int32 and ``cnt_ref`` (1, R, 1) int32 filled
+    slots; ``fill_ref`` (1, 1) int32 SMEM scratch."""
+    rows = c_ref.shape[1]
+    chunks = p_ref.shape[2] // LANES
+    shape = (rows, LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    cx, cy, cz = (c_ref[0, :, a:a + 1] for a in range(3))
 
-    @pl.when(j == 0)
-    def _init():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        idx_ref[...] = jnp.full_like(idx_ref, -1)
+    # -- phase 1: every chunk's hits, as one bit a chunk ------------------
+    words = []
+    count = jnp.zeros(shape, jnp.int32)
+    for q in range(-(-chunks // BITS)):
+        def chunk(b, carry, q=q):
+            word, count = carry
+            c = q * BITS + b
+            start = pl.multiple_of(c * LANES, LANES)
+            px, py, pz = (p_ref[0, a:a + 1, pl.ds(start, LANES)]
+                          for a in range(3))
+            dx, dy, dz = cx - px, cy - py, cz - pz
+            d2 = (dx * dx + dy * dy) + dz * dz
+            hit = (d2 < r2) & (lane + c * LANES < n)
+            word = word | jnp.where(hit, jnp.left_shift(jnp.int32(1), b), 0)
+            return word, count + hit.astype(jnp.int32)
 
-    cnt = cnt_ref[...]
+        word, count = jax.lax.fori_loop(
+            0, min(BITS, chunks - q * BITS), chunk,
+            (jnp.zeros(shape, jnp.int32), count))
+        words.append(word)
 
-    def tile(cnt):
-        # d2[a, b] = |q_a - p_b|^2, component-unrolled (3-vectors).
-        d2 = jnp.zeros((bm, bn), jnp.float32)
-        for c in range(3):
-            d = q_ref[:, c][:, None] - p_ref[:, c][None, :]
-            d2 = d2 + d * d
-        hit = d2 <= radius * radius
-        pos = cnt[:, None] + jnp.cumsum(hit.astype(jnp.int32), axis=1) - 1
-        sel = hit & (pos < k)
-        col = (j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1))
-        onehot = sel[:, :, None] & (pos[:, :, None]
-                                    == jax.lax.broadcasted_iota(
-                                        jnp.int32, (bm, bn, k), 2))
-        upd = jnp.max(jnp.where(onehot, col[:, :, None], -1), axis=1)
-        idx_ref[...] = jnp.where(upd >= 0, upd, idx_ref[...])
-        return cnt + jnp.sum(sel.astype(jnp.int32), axis=1)
+    filled = jnp.minimum(jnp.sum(count, axis=1, keepdims=True), k)
+    cnt_ref[0] = filled
+    fill_ref[0, 0] = jnp.max(filled)
 
-    # Tile-level conditional return: skip if every query here is full.
-    cnt_ref[...] = jax.lax.cond(jnp.all(cnt >= k), lambda c: c, tile, cnt)
+    # -- phase 2: slot j of every row takes the row's next hit ------------
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+    none = jnp.int32(1 << 30)
+
+    def take(j, carry):
+        out, words = carry
+        key = jnp.full(shape, none)
+        for q in reversed(range(len(words))):
+            w = words[q]
+            low = (w & (-w)).astype(jnp.float32)
+            bit = (pltpu.bitcast(low, jnp.int32) >> 23) - 127
+            key = jnp.where(w != 0, (q * BITS + bit) * LANES + lane, key)
+        first = jnp.min(key, axis=1, keepdims=True)       # the point index
+        out = jnp.where(slot == j, first, out)
+        at = lane == (first & (LANES - 1))
+        c = first >> 7                                    # // LANES
+        words = tuple(
+            jnp.where(at & (c >= q * BITS) & (c < (q + 1) * BITS),
+                      w & (w - 1), w)
+            for q, w in enumerate(words))
+        return out, words
+
+    out, _ = jax.lax.fori_loop(0, fill_ref[0, 0], take,
+                               (jnp.zeros((rows, k), jnp.int32),
+                                tuple(words)))
+    pad = jnp.where(filled > 0, out[:, 0:1], 0)
+    idx_ref[0] = jnp.where(slot < filled, out, pad)
 
 
-def make_ballquery_call(m_pad: int, n_pad: int, bm: int, bn: int,
-                        radius: float, k: int, interpret: bool):
-    kernel = functools.partial(ballquery_kernel, radius=radius, k=k, bn=bn)
+def make_ball_query_call(b: int, m_pad: int, n_pad: int, rows: int, n: int,
+                         k: int, radius: float, interpret: bool):
+    """``(b, m_pad, 3)`` centres and ``(b, 3, n_pad)`` points ->
+    ``(b, m_pad, k)`` int32 indices and ``(b, m_pad, 1)`` int32 fills."""
+    r = np.float32(radius)
+    kernel = functools.partial(ball_query_kernel, n=n, k=k, r2=float(r * r))
     return pl.pallas_call(
         kernel,
-        grid=(m_pad // bm, n_pad // bn),
-        in_specs=[
-            pl.BlockSpec((bm, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 3), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((m_pad, k), jnp.int32),
-        ],
+        grid=(b, m_pad // rows),
+        in_specs=[pl.BlockSpec((1, rows, 3), lambda i, j: (i, j, 0)),
+                  pl.BlockSpec((1, 3, n_pad), lambda i, j: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((1, rows, k), lambda i, j: (i, j, 0)),
+                   pl.BlockSpec((1, rows, 1), lambda i, j: (i, j, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, m_pad, k), jnp.int32),
+                   jax.ShapeDtypeStruct((b, m_pad, 1), jnp.int32)],
+        scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32)],
         interpret=interpret,
+        name="ball_query",
     )

@@ -1,2 +1,2 @@
-"""Oracle for the tiled ball-query kernel: the core brute-force reference."""
-from repro.core.ballquery import ball_query_ref  # noqa: F401
+"""Oracle for the ball-query kernel: the MPiNets reference's brute force."""
+from repro.models.mpinets_ref import ball_query as ball_query_ref  # noqa: F401

@@ -1,1 +1,1 @@
-from repro.kernels.fps.ops import fps_pallas  # noqa: F401
+from repro.kernels.fps.ops import fps_sample  # noqa: F401

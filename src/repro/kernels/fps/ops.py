@@ -1,4 +1,4 @@
-"""JIT wrapper: full FPS loop driving the fused update kernel."""
+"""JIT wrapper of the batched FPS kernel: pads and lays out the points."""
 from __future__ import annotations
 
 import functools
@@ -6,30 +6,26 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fps.kernel import make_fps_call
+from repro.kernels.fps.kernel import BLOCK, LANES, make_fps_call
 
 
-@functools.partial(jax.jit, static_argnames=("m", "bn", "interpret"))
-def fps_pallas(points: jax.Array, m: int, first: int = 0, bn: int = 256,
-               interpret: bool = True) -> jax.Array:
-    """Furthest point sampling via the fused Pallas update: (m,) indices."""
-    N = points.shape[0]
-    pad = (-N) % bn
-    pts = jnp.pad(points.astype(jnp.float32), ((0, pad), (0, 0)),
-                  constant_values=1e9)
-    n_pad = pts.shape[0]
-    call = make_fps_call(n_pad, bn, interpret)
-    # padded entries: keep dist at -inf so they are never selected
-    dist0 = jnp.where(jnp.arange(n_pad) < N, jnp.inf, -jnp.inf
-                      ).astype(jnp.float32)
-    idx0 = jnp.zeros((m,), jnp.int32).at[0].set(first)
+def _pad_to(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
 
-    def body(i, carry):
-        dist, idx = carry
-        sel = jax.lax.dynamic_slice(pts, (idx[i - 1], 0), (1, 3))
-        ndist, bmax, barg = call(pts, dist, sel)
-        nxt = barg[jnp.argmax(bmax)]
-        return ndist, idx.at[i].set(nxt)
 
-    _, idx = jax.lax.fori_loop(1, m, body, (dist0, idx0))
-    return idx
+@functools.partial(jax.jit, static_argnames=("m", "interpret"))
+def fps_sample(xyz: jax.Array, m: int, interpret: bool | None = None
+               ) -> jax.Array:
+    """Farthest point sampling of ``m`` picks from each of B clouds in one
+    kernel call: ``xyz`` (B, N, 3) -> (B, m) int32 indices (see
+    :mod:`repro.kernels.fps.kernel` for the semantics).  ``interpret``
+    defaults to the interpreter off the TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    B, N, _ = xyz.shape
+    b_pad, n_pad = _pad_to(B, BLOCK), _pad_to(N, LANES)
+    m_pad = _pad_to(m, LANES)
+    planes = jnp.transpose(xyz.astype(jnp.float32), (2, 0, 1))
+    planes = jnp.pad(planes, ((0, 0), (0, b_pad - B), (0, n_pad - N)))
+    call = make_fps_call(b_pad // BLOCK, n_pad, m_pad, N, m, interpret)
+    return call(planes)[:B, :m]

@@ -1,2 +1,2 @@
-"""Oracle for the FPS kernel: the core jnp implementation."""
-from repro.core.fps import farthest_point_sampling as fps_ref  # noqa: F401
+"""Oracle for the FPS kernel: the MPiNets reference's brute-force FPS."""
+from repro.models.mpinets_ref import fps as fps_ref  # noqa: F401

@@ -1,6 +1,5 @@
 """Ball query (P-Ray == P-Sphere == brute force) and FPS invariants."""
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
@@ -8,9 +7,8 @@ from conftest import seeded_property
 
 from repro.core.ballquery import (ball_query_pray, ball_query_psphere,
                                   ball_query_ref)
-from repro.core.fps import (farthest_point_sampling, random_sampling,
-                            sampling_spread)
 from repro.core.octree import build_octree
+from repro.kernels.fps import fps_sample
 
 
 def _sets(idx, cnt):
@@ -75,28 +73,34 @@ def test_ballquery_property_random(seed):
     _ballquery_property(seed)
 
 
+def _spread(pts, idx):
+    """Mean distance from every point to its nearest sample: lower is
+    better coverage."""
+    d2 = ((pts[:, None, :] - pts[idx][None, :, :]) ** 2).sum(-1)
+    return float(np.sqrt(d2.min(axis=1)).mean())
+
+
 def test_fps_invariants():
     rs = np.random.RandomState(2)
-    pts = jnp.asarray(rs.uniform(-1, 1, (2000, 3)).astype(np.float32))
-    idx = farthest_point_sampling(pts, 64)
-    idx_np = np.asarray(idx)
+    pts = rs.uniform(-1, 1, (2000, 3)).astype(np.float32)
+    idx_np = np.asarray(fps_sample(jnp.asarray(pts[None]), 64))[0]
     assert idx_np[0] == 0
     assert len(set(idx_np.tolist())) == 64              # distinct points
     # FPS spread beats random sampling (coverage metric, averaged seeds)
-    fps_spread = float(sampling_spread(pts, idx))
-    rnd = [float(sampling_spread(pts, random_sampling(
-        jax.random.PRNGKey(s), 2000, 64))) for s in range(5)]
-    assert fps_spread < np.mean(rnd)
+    rnd = [_spread(pts, np.random.RandomState(s).choice(2000, 64, False))
+           for s in range(5)]
+    assert _spread(pts, idx_np) < np.mean(rnd)
 
 
 def test_fps_matches_numpy_oracle():
     rs = np.random.RandomState(3)
     pts = rs.uniform(-1, 1, (300, 3)).astype(np.float32)
-    got = np.asarray(farthest_point_sampling(jnp.asarray(pts), 20))
-    dist = np.full(300, np.inf)
+    got = np.asarray(fps_sample(jnp.asarray(pts[None]), 20))[0]
+    dist = np.full(300, np.inf, np.float32)
     idx = [0]
     for _ in range(19):
-        d = ((pts - pts[idx[-1]]) ** 2).sum(-1)
-        dist = np.minimum(dist, d)
+        d = pts - pts[idx[-1]]
+        dist = np.minimum(dist, (d[:, 0] * d[:, 0] + d[:, 1] * d[:, 1])
+                          + d[:, 2] * d[:, 2])
         idx.append(int(dist.argmax()))
     assert (got == np.asarray(idx)).all()

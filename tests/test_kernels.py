@@ -29,24 +29,31 @@ def test_sact_kernel(M, N, bm, bn, sph):
 @pytest.mark.parametrize("M,N,r,k", [(70, 1000, 0.3, 16), (33, 500, 0.5, 4),
                                      (16, 128, 0.2, 8)])
 def test_ballquery_kernel(M, N, r, k):
-    from repro.kernels.ballquery.ops import ball_query_tiled
+    """The batched kernel's slots and fills equal the brute-force oracle's,
+    per problem, whether or not a ball overflows its k slots."""
+    from repro.kernels.ballquery.ops import ball_query
     from repro.kernels.ballquery.ref import ball_query_ref
     rs = np.random.RandomState(M)
-    pts = jnp.asarray(rs.uniform(-1, 1, (N, 3)).astype(np.float32))
-    qs = jnp.asarray(rs.uniform(-1, 1, (M, 3)).astype(np.float32))
-    idx_k, cnt_k = ball_query_tiled(qs, pts, r, k, bm=32, bn=64)
-    idx_r, cnt_r = ball_query_ref(pts, qs, r, k)
-    assert bool(jnp.all(cnt_k == cnt_r))
-    assert bool(jnp.all(idx_k == idx_r))     # exact: same first-k order
+    pts = jnp.asarray(rs.uniform(-1, 1, (2, N, 3)).astype(np.float32))
+    qs = jnp.asarray(rs.uniform(-1, 1, (2, M, 3)).astype(np.float32))
+    idx_k, cnt_k = ball_query(pts, qs, r, k)
+    for b in range(2):
+        idx_r, cnt_r = ball_query_ref(pts[b], qs[b], r, k)
+        assert bool(jnp.all(cnt_k[b] == cnt_r))
+        assert bool(jnp.all(idx_k[b] == idx_r))  # same first-k order
 
 
-@pytest.mark.parametrize("N,m,bn", [(1000, 33, 128), (513, 16, 64)])
-def test_fps_kernel(N, m, bn):
-    from repro.kernels.fps.ops import fps_pallas
+@pytest.mark.parametrize("N,m,B", [(1000, 33, 3), (513, 16, 9)])
+def test_fps_kernel(N, m, B):
+    """Every pick of every problem (9 problems: two blocks of 8) equals the
+    brute-force oracle's."""
+    from repro.kernels.fps.ops import fps_sample
     from repro.kernels.fps.ref import fps_ref
     rs = np.random.RandomState(N)
-    pts = jnp.asarray(rs.uniform(-1, 1, (N, 3)).astype(np.float32))
-    assert bool(jnp.all(fps_pallas(pts, m, bn=bn) == fps_ref(pts, m)))
+    pts = jnp.asarray(rs.uniform(-1, 1, (B, N, 3)).astype(np.float32))
+    got = fps_sample(pts, m)
+    for b in range(B):
+        assert bool(jnp.all(got[b] == fps_ref(pts[b], m)))
 
 
 @pytest.mark.parametrize("BH,T,D,chunk", [(3, 70, 16, 16), (2, 64, 32, 32),

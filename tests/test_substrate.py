@@ -137,26 +137,27 @@ def test_mcl_engines_agree_and_converge():
 
 
 def test_planner_bc_loss_decreases():
-    from repro.models.planner import init_planner, planner_loss
+    """Behaviour cloning of the MPiNets policy (small preset) lowers the
+    loss on a fixed batch."""
+    from repro.models.planner import SMALL, bc_loss, init_mpinets
     from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
     rs = np.random.RandomState(0)
-    B = 16
-    batch = {
-        "cloud": jnp.asarray(rs.uniform(-1, 1, (B, 256, 3)
-                                        ).astype(np.float32)),
-        "q": jnp.asarray(rs.uniform(-1, 1, (B, 7)).astype(np.float32)),
-        "goal": jnp.asarray(rs.uniform(-1, 1, (B, 7)).astype(np.float32)),
-        "expert_delta": jnp.asarray(
-            rs.uniform(-0.3, 0.3, (B, 7)).astype(np.float32)),
-    }
-    params = init_planner(jax.random.PRNGKey(0), feat_dim=64, hidden=64)
+    B = 8
+    model = init_mpinets(SMALL, seed=0)
+    obstacles = jnp.asarray(rs.uniform(-1, 1, (B, SMALL.obstacle_points, 3)
+                                       ).astype(np.float32))
+    target = jnp.asarray(rs.uniform(-1, 1, (B, SMALL.target_points, 3)
+                                    ).astype(np.float32))
+    q = jnp.asarray(rs.uniform(-1, 1, (B, 7)).astype(np.float32))
+    expert = jnp.asarray(rs.uniform(-0.1, 0.1, (B, 7)).astype(np.float32))
+    params = model.params
     cfg = OptConfig(lr=3e-3, warmup_steps=0, total_steps=30)
     st = init_opt_state(params, cfg)
     lg = jax.jit(jax.value_and_grad(
-        lambda p, b: planner_loss(p, b, "random", jax.random.PRNGKey(1))[0]))
+        lambda p: bc_loss(model, p, obstacles, target, q, expert)))
     losses = []
     for i in range(15):
-        loss, g = lg(params, batch)
+        loss, g = lg(params)
         params, st, _ = adamw_update(params, g, st, cfg)
         losses.append(float(loss))
     assert losses[-1] < losses[0] * 0.8, losses

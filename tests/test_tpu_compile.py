@@ -4,7 +4,9 @@ Nothing here runs on a chip: each test compiles for a described (not
 attached) ``v5e`` topology, so what the TPU compiler refuses — tiling of
 blocks, VMEM over the scoped limit, primitives Mosaic cannot lower — fails
 here at no chip time.  Shapes are those of the cubby scene at 524,288
-points, depth 7, against the paper's Table III batch of 10,500 link OBBs.
+points, depth 7, against the paper's Table III batch of 10,500 link OBBs,
+and of MPiNets' set abstractions at its published widths over 8 problems
+(6,272 points each, 512 then 128 centres, 128 neighbours).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under several test workers only the
@@ -19,7 +21,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.octree import META_ROW_ALIGN
 from repro.core.quantize import META_FORMAT_WORDS
+from repro.kernels.ballquery.kernel import make_ball_query_call
+from repro.kernels.ballquery.ops import MAX_ROWS
 from repro.kernels.compact.ops import stream_compact
+from repro.kernels.fps.kernel import make_fps_call
 from repro.kernels.persist.kernel import RESIDENT_WINDOW, make_persist_call
 from repro.kernels.persist.ops import (DEFAULT_VMEM_BUDGET, MAX_TILE_BQ,
                                        MAX_TILE_FRONTIER, choose_meta_layout,
@@ -140,3 +145,35 @@ def test_stream_compaction_compiles(one_chip):
     _compiled_text(lambda m, v: stream_compact(m, v, CAPACITY),
                    _shape(one_chip, (8 * CAPACITY,), jnp.bool_),
                    _shape(one_chip, (8 * CAPACITY, 2)))
+
+
+#: MPiNets' planning batch: problems, points, and (centres, radius) of its
+#: two set abstractions, whose input clouds are 6,272 and 512 points.
+PROBLEMS = 8
+MPINETS_SA = ((6272, 512, 0.05), (512, 128, 0.3))
+
+
+@pytest.mark.parametrize("n,m", [(n, m) for n, m, _ in MPINETS_SA],
+                         ids=["sa1", "sa2"])
+def test_fps_sample_compiles(one_chip, n, m):
+    """Every pick of a set abstraction for the whole batch in one call."""
+    n_pad = -(-n // 128) * 128
+    call = make_fps_call(PROBLEMS // 8, n_pad, -(-m // 128) * 128, n, m,
+                         False)
+    text = _compiled_text(call, _shape(one_chip, (3, PROBLEMS, n_pad),
+                                       jnp.float32))
+    _assert_named_kernel(text, "fps_sample")
+
+
+@pytest.mark.parametrize("n,m,r", MPINETS_SA, ids=["sa1", "sa2"])
+def test_ball_query_compiles(one_chip, n, m, r):
+    """128 neighbours of every centre of the batch in one call."""
+    n_pad = -(-n // 128) * 128
+    call = make_ball_query_call(PROBLEMS, m, n_pad, MAX_ROWS, n, 128, r,
+                                False)
+    text = _compiled_text(call, _shape(one_chip, (PROBLEMS, m, 3),
+                                       jnp.float32),
+                          _shape(one_chip, (PROBLEMS, 3, n_pad),
+                                 jnp.float32))
+    _assert_named_kernel(text, "ball_query")
+
